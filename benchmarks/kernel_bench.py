@@ -55,8 +55,8 @@ def main():
     # paged decode attention
     nb, p = 512, 64
     qd = jax.random.normal(k, (8, h, d), jnp.bfloat16)
-    kp = jax.random.normal(k, (nb, p, kv, d), jnp.bfloat16)
-    vp = jax.random.normal(k, (nb, p, kv, d), jnp.bfloat16)
+    kp = jax.random.normal(k, (nb, p, kv * d), jnp.bfloat16)
+    vp = jax.random.normal(k, (nb, p, kv * d), jnp.bfloat16)
     table = jnp.tile(jnp.arange(64)[None], (8, 1))
     ctx = jnp.full((8,), 64 * p - 3)
     pa = jax.jit(lambda *a: ops.paged_attention(*a, impl="blocked"))

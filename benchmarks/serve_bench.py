@@ -816,6 +816,8 @@ def _dispersion(sps):
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     repeats = 8        # multiple of the mode count: every mode sees
     if "--repeats" in sys.argv:   # every rotation position equally
         repeats = int(sys.argv[sys.argv.index("--repeats") + 1])

@@ -86,7 +86,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
     """q [B,Sq,H,D]; k,v [B,Skv,KV,D] -> [B,Sq,H,D]."""
     if segment_ids is not None:
         raise NotImplementedError(
-            "segment_ids: use the blocked-jnp lowering (ops.py falls back)")
+            "segment_ids: use the blocked-jnp lowering (impl='blocked')")
     b, sq, h, d = q.shape
     skv, kv = k.shape[1], k.shape[2]
     group = h // kv

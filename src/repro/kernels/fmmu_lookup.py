@@ -1,110 +1,25 @@
-"""Pallas TPU kernel for a bare batched CMT probe (probe-only).
+"""Bare batched CMT probe (probe-only, no side effects).
 
-Hardware adaptation (DESIGN.md §2): the paper's CAM-style parallel tag
-compare becomes a *one-hot matmul gather* — set indices are expanded to
-a one-hot [blk, S] matrix and multiplied against the VMEM-resident tag /
-data arrays, turning the irregular per-request set lookup into two MXU
-matmuls (TPUs have no CAM, but they have a 128x128 systolic array).
-The whole CMT (paper geometry: 512 sets x 4 ways x 8 entries x 4B ≈
-64KB tags+data) fits in VMEM, exactly like the SRAM block of the
-hardware unit; only the request vector streams through the grid.
-
-Fused translate pipeline (DESIGN.md): the batch engine's hot path no
-longer uses this probe-only kernel — `fmmu_translate.py` fuses the
-probe with the backing-table fallback and the ref-bit touch so
-`translate_batch` issues ONE kernel per mixed-op batch. This kernel
-remains the probe primitive for the unfused reference path
-(`core/fmmu/batch.*_unfused`, equivalence tests + benchmarks) and for
-callers that need a side-effect-free probe.
+The probe primitive for the unfused reference path
+(`core/fmmu/batch.*_unfused`, equivalence tests + benchmarks). It runs
+the fused translate kernel (`fmmu_translate.py`) with no ref-bit touch
+and a one-entry all-NIL backing table, so a miss reads NIL: one Pallas
+kernel serves both, and what compiles for the TPU there compiles here.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-
-def gather16(onehot, vals2d):
-    """Bit-exact int32 one-hot gather on the MXU: two f32 matmuls over
-    the 16-bit halves (lo = v & 0xffff in [0, 2^16), hi = v >> 16 in
-    [-2^15, 2^15) — each f32-exact), recombined in int32. Needed
-    because gathered values may exceed f32's 2^24 exact-integer range:
-    the paging layer tags host-tier block ids at 1<<24 and above.
-    onehot [r, c] f32 (exactly one 1.0 per row, or all-zero rows);
-    vals2d [c, k] int32 -> [r, k] int32."""
-    lo = jax.lax.dot(onehot, (vals2d & 0xffff).astype(jnp.float32),
-                     preferred_element_type=jnp.float32)
-    hi = jax.lax.dot(onehot, (vals2d >> 16).astype(jnp.float32),
-                     preferred_element_type=jnp.float32)
-    return hi.astype(jnp.int32) * 65536 + lo.astype(jnp.int32)
-
-
-def _fl_kernel(tags_ref, valid_ref, data_ref, dlpn_ref, hit_ref, dppn_ref,
-               set_ref, way_ref, *, entries_per_block, n_sets, n_ways,
-               blk):
-    dlpns = dlpn_ref[...]                              # [blk]
-    block_id = dlpns // entries_per_block
-    offset = jnp.mod(dlpns, entries_per_block)
-    set_idx = jnp.mod(block_id, n_sets)
-    active = dlpns >= 0
-
-    # one-hot gather of the probe sets via the MXU
-    onehot = (set_idx[:, None] ==
-              jax.lax.broadcasted_iota(jnp.int32, (blk, n_sets), 1)
-              ).astype(jnp.float32)                    # [blk, S]
-    tags = tags_ref[...].astype(jnp.float32)           # [S, W]
-    valid = valid_ref[...].astype(jnp.float32)         # [S, W]
-    row_tags = jax.lax.dot(onehot, tags,
-                           preferred_element_type=jnp.float32)
-    row_valid = jax.lax.dot(onehot, valid,
-                            preferred_element_type=jnp.float32)
-    match = (row_tags == block_id[:, None].astype(jnp.float32)) & \
-        (row_valid > 0.5)                              # [blk, W]
-    hit = match.any(axis=1) & active
-    way = jnp.argmax(match, axis=1).astype(jnp.int32)
-
-    e = entries_per_block
-    data2d = data_ref[...].reshape(n_sets, n_ways * e)
-    row_data = gather16(onehot, data2d)                # [blk, W*E]
-    col = way * e + offset
-    picked = jnp.take_along_axis(row_data, col[:, None], axis=1)[:, 0]
-    dppn = jnp.where(hit, picked, -1)
-
-    hit_ref[...] = hit.astype(jnp.int32)
-    dppn_ref[...] = dppn
-    set_ref[...] = set_idx.astype(jnp.int32)
-    way_ref[...] = way
+from repro.kernels.fmmu_translate import fmmu_translate
 
 
 def fmmu_lookup(tags, valid, data, dlpns, *, entries_per_block,
                 block_size=256, interpret=False):
     """tags [S,W] int32; valid [S,W] bool; data [S,W,E] int32;
     dlpns [Bq] int32 -> (hit bool, dppn, set, way)."""
-    n_sets, n_ways = tags.shape
-    bq = dlpns.shape[0]
-    blk = min(block_size, bq)
-    bq_p = -(-bq // blk) * blk
-    if bq_p != bq:
-        dlpns = jnp.pad(dlpns, (0, bq_p - bq), constant_values=-1)
-    kernel = functools.partial(
-        _fl_kernel, entries_per_block=entries_per_block, n_sets=n_sets,
-        n_ways=n_ways, blk=blk)
-    full = lambda *_: tuple(0 for _ in range(2))
-    hit, dppn, set_idx, way = pl.pallas_call(
-        kernel,
-        grid=(bq_p // blk,),
-        in_specs=[
-            pl.BlockSpec((n_sets, n_ways), lambda i: (0, 0)),
-            pl.BlockSpec((n_sets, n_ways), lambda i: (0, 0)),
-            pl.BlockSpec((n_sets, n_ways, entries_per_block),
-                         lambda i: (0, 0, 0)),
-            pl.BlockSpec((blk,), lambda i: (i,)),
-        ],
-        out_specs=[pl.BlockSpec((blk,), lambda i: (i,))] * 4,
-        out_shape=[jax.ShapeDtypeStruct((bq_p,), jnp.int32)] * 4,
-        interpret=interpret,
-    )(tags, valid.astype(jnp.int32), data, dlpns)
-    return (hit[:bq].astype(bool), dppn[:bq], set_idx[:bq], way[:bq])
+    hit, dppn, set_idx, way, _ = fmmu_translate(
+        tags, valid, jnp.zeros_like(valid), data,
+        jnp.full((1,), -1, jnp.int32), dlpns,
+        jnp.zeros(dlpns.shape, bool), entries_per_block=entries_per_block,
+        block_size=block_size, interpret=interpret)
+    return hit, dppn, set_idx, way

@@ -11,6 +11,11 @@
                      the per-kernel allclose tests.
   blocked          — chunked pure-jnp engine (same tiling as the kernel)
   naive            — O(S^2) oracle (tests only)
+
+The Pallas attention kernels take no segment or page masks: a masked
+call that selects a Pallas lowering (by name or through ``auto`` on a
+TPU) raises instead of quietly running another lowering, so a caller
+with masks asks for ``blocked`` (or ``naive``) by name.
 """
 from __future__ import annotations
 
@@ -35,10 +40,8 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
                     segment_ids=None, bidirectional=False, impl=None,
                     q_chunk=512, kv_chunk=512):
     sel = _default_impl(impl)
-    if sel in ("pallas", "pallas_interpret") and segment_ids is not None:
-        sel = "blocked"   # packing masks: blocked lowering handles segments
     if sel in ("pallas", "pallas_interpret"):
-        from repro.kernels import flash_attention as fa
+        from repro.kernels import flash_attention as fa   # raises on segments
         return fa.flash_attention(
             q, k, v, causal=causal, window=window, softcap=softcap,
             segment_ids=segment_ids, bidirectional=bidirectional,
@@ -57,22 +60,30 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
 def paged_attention(q, k_pool, v_pool, block_table, ctx_lens, *,
                     softcap=0.0, window=0, page_mask=None,
                     return_stats=False, impl=None, pages_per_chunk=None):
+    """q [B,H,D]; lane-dense pools [NB,P,KV*D] (kernels/paged_attention
+    .py says why); the jnp lowerings view them as [NB,P,KV,D]."""
     sel = _default_impl(impl)
     if sel in ("pallas", "pallas_interpret") and page_mask is not None:
-        sel = "blocked"   # striped-page masking: blocked lowering
+        raise NotImplementedError(
+            "paged_attention: the Pallas kernel takes no page_mask; "
+            "pass impl='blocked' for masked calls")
     if sel in ("pallas", "pallas_interpret"):
         from repro.kernels import paged_attention as pa
         return pa.paged_attention(
             q, k_pool, v_pool, block_table, ctx_lens, softcap=softcap,
             window=window, return_stats=return_stats,
             interpret=(sel == "pallas_interpret"))
+    nb, p, kvd = k_pool.shape
+    d = q.shape[-1]
+    k_pool = k_pool.reshape(nb, p, kvd // d, d)
+    v_pool = v_pool.reshape(nb, p, kvd // d, d)
     if sel == "blocked":
         if pages_per_chunk is None:
             # auto: chunking bounds live memory at O(c * P) per (B,H),
             # but every chunk is a scan iteration of tiny ops — the
             # dominant CPU decode cost — so take the whole table in one
             # chunk whenever it fits a modest live window
-            maxp, p = block_table.shape[1], k_pool.shape[1]
+            maxp = block_table.shape[1]
             pages_per_chunk = maxp if maxp * p <= 1024 else 8
         return ref.paged_attention_blocked(
             q, k_pool, v_pool, block_table, ctx_lens, softcap=softcap,

@@ -1,7 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
-# ^ MUST precede any jax import: jax locks the device count on first init.
+# a CPU-side tool: 512 virtual host devices stand in for the pods, and a
+# TPU (if the machine has one) stays free for the process that serves
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ both MUST precede any jax import: jax locks its backends on first init.
 
 """Multi-pod dry-run: lower + compile every (arch x shape) on the
 production meshes (16x16 single-pod; 2x16x16 multi-pod) with

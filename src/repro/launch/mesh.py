@@ -8,8 +8,6 @@ from repro.parallel.sharding import ParallelCtx, make_mesh
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    # version-portable wrapper: jax.sharding.AxisType only exists on
-    # newer wheels than the pinned 0.4.37
     return make_mesh(shape, axes)
 
 

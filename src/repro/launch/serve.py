@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch, smoke_config
+from repro.launch import compile_cache
 from repro.models import Runtime, build_model
 from repro.serving.engine import ServeEngine
 
@@ -30,6 +31,7 @@ def main(argv=None):
     ap.add_argument("--host-blocks", type=int, default=0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = get_arch(args.arch)
     if args.smoke:
@@ -64,7 +66,7 @@ def main(argv=None):
         "decode_steps": eng.metrics["decode_steps"],
         "preemptions": eng.metrics["preemptions"],
         "tok_per_s": round(eng.metrics["generated"] / max(wall, 1e-9), 1),
-        "fmmu_map": stats,
+        "fmmu_map": stats.as_dict(),
         "pool_peak_blocks": eng.kvm.pool.stats.peak_used,
     }
     print(json.dumps(out, indent=2))

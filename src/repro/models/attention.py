@@ -49,6 +49,13 @@ def attention_specs(cfg, *, cross: bool = False):
     return specs
 
 
+def _masked_impl(rt: Runtime) -> str:
+    """Kernel lowering for calls that carry segment or page masks: the
+    Pallas kernels take none (ops raises), so these name the blocked
+    lowering, or the naive oracle when the runtime asks for it."""
+    return "naive" if rt.kernel_impl == "naive" else "blocked"
+
+
 # ----------------------------------------------------------------------
 def _project_qkv(params, x, cfg, rt, positions, *, rope: bool = True):
     """x [B,S,d] -> q [B,S,H,hd], k,v [B,S,KV,hd] (compute dtype)."""
@@ -77,7 +84,8 @@ def attn_forward(params, x, cfg, rt: Runtime, *, positions, kind="global",
     out = ops.flash_attention(
         q, k, v, causal=not bidirectional, window=window,
         softcap=cfg.attn_softcap, segment_ids=segs,
-        bidirectional=bidirectional, impl=rt.kernel_impl,
+        bidirectional=bidirectional,
+        impl=rt.kernel_impl if segs is None else _masked_impl(rt),
         q_chunk=rt.q_chunk, kv_chunk=rt.kv_chunk)
     y = jnp.einsum("bshk,hkd->bsd", out, common.cast(params["wo"], rt.compute_dtype))
     if return_kv:
@@ -96,7 +104,9 @@ def cross_forward(params, x, kv_cache, cfg, rt: Runtime, *, src_valid=None):
         seg_q = jnp.ones(q.shape[:2], jnp.int32)
         segs = (seg_q, src_valid.astype(jnp.int32))
     out = ops.flash_attention(q, k, v, causal=False, bidirectional=True,
-                              segment_ids=segs, impl=rt.kernel_impl,
+                              segment_ids=segs,
+                              impl=(rt.kernel_impl if segs is None
+                                    else _masked_impl(rt)),
                               q_chunk=rt.q_chunk, kv_chunk=rt.kv_chunk)
     return jnp.einsum("bshk,hkd->bsd", out, common.cast(params["wo"], cd))
 
@@ -115,8 +125,10 @@ def cross_kv(params, enc_out, cfg, rt: Runtime):
 def write_kv_page(pool_k, pool_v, k_new, v_new, block_table, ctx_lens,
                   page_size: int):
     """Scatter one new token's K/V into the paged pool.
-    k_new/v_new [B,KV,hd]; returns updated pools."""
+    pools [NB,P,KV*hd]; k_new/v_new [B,KV,hd]; returns updated pools."""
     b = k_new.shape[0]
+    k_new = k_new.reshape(b, -1)
+    v_new = v_new.reshape(b, -1)
     logical = ctx_lens // page_size
     offs = ctx_lens % page_size
     pages = block_table[jnp.arange(b), logical]
@@ -128,7 +140,7 @@ def write_kv_page(pool_k, pool_v, k_new, v_new, block_table, ctx_lens,
 def attn_decode_paged(params, x, cfg, rt: Runtime, *, pool_k, pool_v,
                       block_table, ctx_lens, kind="global",
                       return_stats=False):
-    """One-token decode. x [B,d]; pools [NB,P,KV,hd]; returns
+    """One-token decode. x [B,d]; pools [NB,P,KV*hd]; returns
     (y [B,d], pool_k, pool_v) (+ (m,l) stats for cross-shard combine)."""
     positions = ctx_lens[:, None]                      # [B,1]
     q, k, v = _project_qkv(params, x[:, None, :], cfg, rt, positions)
@@ -196,17 +208,17 @@ def attn_decode_paged_striped(params, x, cfg, rt: Runtime, ctx, *,
         # set() for the owning shard, a literal +0 elsewhere — immune to
         # index collisions and to any OOB-mode lowering surprises.
         rows = jnp.where(t_owned, tgt - lo, 0)
-        own3 = t_owned[:, None, None]
+        own2 = t_owned[:, None]
         cur_k = pk[rows, offs]
         cur_v = pv[rows, offs]
         pk = pk.at[rows, offs].add(
-            jnp.where(own3, kn.astype(pk.dtype) - cur_k, 0))
+            jnp.where(own2, kn.reshape(bb, -1).astype(pk.dtype) - cur_k, 0))
         pv = pv.at[rows, offs].add(
-            jnp.where(own3, vn.astype(pv.dtype) - cur_v, 0))
+            jnp.where(own2, vn.reshape(bb, -1).astype(pv.dtype) - cur_v, 0))
         o, (m, l) = ops.paged_attention(
             qb, pk, pv, local_table, ctxl + 1, softcap=cfg.attn_softcap,
             window=window, page_mask=owned, return_stats=True,
-            impl=rt.kernel_impl, pages_per_chunk=rt.paged_chunk)
+            impl=_masked_impl(rt), pages_per_chunk=rt.paged_chunk)
         outs = jax.lax.all_gather(o.astype(jnp.float32), combine_axes)
         ms = jax.lax.all_gather(m, combine_axes)
         ls = jax.lax.all_gather(l, combine_axes)
@@ -215,7 +227,7 @@ def attn_decode_paged_striped(params, x, cfg, rt: Runtime, ctx, *,
 
     dspec = "data" if batch_sharded else None
     pool_spec = P(own_axes if len(own_axes) > 1 else own_axes[0],
-                  None, None, None)
+                  None, None)
     fn = shard_map(
         body, mesh=mesh,
         in_specs=(P(dspec, None, None), P(dspec, None, None),

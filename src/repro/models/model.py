@@ -190,9 +190,9 @@ class Model:
                 # combine axes (the flash-channel analogy)
                 b = None  # decided by batch shardability at trace time
                 shape_b = None
-                pool = P(None, None, ("data", "model"), None, None, None)
+                pool = P(None, None, ("data", "model"), None, None)
             else:
-                pool = P(None, None, "data", None, None, "model")
+                pool = P(None, None, "data", None, "model")
             specs["pool_k"] = pool
             specs["pool_v"] = pool
         if any(cfg.layer_kind(i) == "mamba" for i in range(cfg.n_layers)):

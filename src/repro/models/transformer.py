@@ -193,7 +193,9 @@ def stack_forward(params, x, cfg, rt: Runtime, ctx, *, positions,
 # ----------------------------------------------------------------------
 def init_decode_caches(cfg, rt: Runtime, batch: int, max_pages_per_seq: int,
                        n_blocks: int, dtype, *, src_len: int = 0):
-    """Allocate paged KV pools / SSM states, stacked [n_periods, L_kind, ...]."""
+    """Allocate paged KV pools / SSM states, stacked [n_periods, L_kind, ...].
+    KV pools are lane-dense [.., n_blocks, page, KV*hd]
+    (kernels/paged_attention.py)."""
     period = cfg.period
     n_periods = cfg.n_layers // period
     attn_js = [j for j in range(period) if cfg.layer_kind(j) == "attn"]
@@ -201,7 +203,7 @@ def init_decode_caches(cfg, rt: Runtime, batch: int, max_pages_per_seq: int,
     caches: Dict[str, Any] = {}
     if attn_js:
         shape = (n_periods, len(attn_js), n_blocks, rt.page_size,
-                 cfg.n_kv_heads, cfg.head_dim)
+                 cfg.n_kv_heads * cfg.head_dim)
         caches["pool_k"] = jnp.zeros(shape, dtype)
         caches["pool_v"] = jnp.zeros(shape, dtype)
     if ssm_js:

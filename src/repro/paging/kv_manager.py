@@ -283,7 +283,7 @@ class KVPageManager:
         if C > 1:
             self._retrans_fn = jax.jit(
                 functools.partial(self._retranslate_sharded, self.geom,
-                                  C, n_slots, max_pages),
+                                  C, n_slots, max_pages, self.mesh),
                 donate_argnums=(0,))
             self._set_alloc = jax.jit(fb.set_allocator_sharded,
                                       donate_argnums=(0,))
@@ -444,17 +444,27 @@ class KVPageManager:
         return fmmu, out.reshape(n_slots, max_pages)
 
     @staticmethod
-    def _retranslate_sharded(geom, C, n_slots, max_pages, fmmu):
+    def _retranslate_sharded(geom, C, n_slots, max_pages, mesh, fmmu):
         """Sharded retranslation oracle: every channel looks up all of
         its local dlpns, and the per-channel results interleave back to
-        the global order (global dlpn d = local l * C + channel c)."""
+        the global order (global dlpn d = local l * C + channel c).
+        On a channel mesh the lookups run under shard_map: a Pallas
+        kernel cannot be partitioned automatically."""
 
         def body(fm):
             L = geom.n_tvpns * geom.entries_per_tp
             return fb.lookup_batch(geom, fm,
                                    jnp.arange(L, dtype=jnp.int32))
 
-        fmmu, outs = jax.vmap(body)(fmmu)
+        per_channel = jax.vmap(body)
+        if mesh is not None:
+            from jax.sharding import PartitionSpec as P
+
+            from repro.parallel.sharding import shard_map
+            per_channel = shard_map(per_channel, mesh=mesh,
+                                    in_specs=P("channel"),
+                                    out_specs=P("channel"))
+        fmmu, outs = per_channel(fmmu)
         flat = fb.interleave_table(outs, n_slots * max_pages)
         return fmmu, flat.reshape(n_slots, max_pages)
 

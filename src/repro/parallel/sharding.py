@@ -19,36 +19,20 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    # jax.sharding.AxisType (and make_mesh's axis_types kwarg) only exist
-    # on newer jax; Auto is the default behaviour either way, so fall
-    # back cleanly on wheels that predate explicit axis types.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False,
               axis_names=None):
-    """Version-portable shard_map: prefers the top-level jax.shard_map
-    (check_vma / axis_names API), falls back to
-    jax.experimental.shard_map on older wheels (check_rep; partial
-    manualness expressed through its `auto` complement)."""
-    if hasattr(jax, "shard_map"):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
+    """``jax.shard_map`` with replication checks off by default;
+    ``axis_names`` (optional) limits the manual axes."""
     kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_vma)
+              check_vma=check_vma)
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return _shard_map(f, **kw)
+        kw["axis_names"] = axis_names
+    return jax.shard_map(f, **kw)
 
 
 def channel_mesh(n_channels: int) -> Mesh:

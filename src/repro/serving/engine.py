@@ -1839,14 +1839,13 @@ def _scatter_prefill(cfg: ArchConfig, rt: Runtime, caches, cols, table_row,
             pad = npages * page - s
             kp = jnp.pad(k[:, 0], ((0, 0), (0, pad), (0, 0), (0, 0)))
             vp = jnp.pad(v[:, 0], ((0, 0), (0, pad), (0, 0), (0, 0)))
-            kp = kp.reshape(np_, npages, page, kvh, hd)
-            vp = vp.reshape(np_, npages, page, kvh, hd)
+            kp = kp.reshape(np_, npages, page, kvh * hd)
+            vp = vp.reshape(np_, npages, page, kvh * hd)
             rows = table_row[:npages]
             ai = a_of[j]
-            # scatter: pool [NP, A, NB, P, KV, hd]
+            # scatter: pool [NP, A, NB, P, KV*hd]
             caches["pool_k"] = caches["pool_k"].at[:, ai, rows].set(
-                kp.astype(caches["pool_k"].dtype).transpose(0, 1, 2, 3, 4),
-                mode="drop")
+                kp.astype(caches["pool_k"].dtype), mode="drop")
             caches["pool_v"] = caches["pool_v"].at[:, ai, rows].set(
                 vp.astype(caches["pool_v"].dtype), mode="drop")
         if "ssm" in col:

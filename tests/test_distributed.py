@@ -209,9 +209,9 @@ def test_striped_paged_decode_attention_exact():
     params = attention.init_attention(jax.random.key(0), cfg, jnp.float32)
     NB, page, maxp = 64, 8, 8
     pool_k = jax.random.normal(jax.random.key(1),
-                               (NB, page, cfg.n_kv_heads, cfg.head_dim))
+                               (NB, page, cfg.n_kv_heads * cfg.head_dim))
     pool_v = jax.random.normal(jax.random.key(2),
-                               (NB, page, cfg.n_kv_heads, cfg.head_dim))
+                               (NB, page, cfg.n_kv_heads * cfg.head_dim))
     errs = {}
     # batch=1: pages striped across every chip, combine over all axes
     table = jax.random.permutation(jax.random.key(3),

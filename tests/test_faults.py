@@ -25,7 +25,7 @@ from repro.models import Runtime, build_model
 from repro.paging.kv_manager import KVPageManager
 from repro.paging.pool import BlockPool, OutOfBlocks, PoolExhausted
 from repro.serving.engine import ServeEngine
-from tests._hyp import example, given, settings, st
+from hypothesis import example, given, settings, strategies as st
 
 pytestmark = pytest.mark.faults
 

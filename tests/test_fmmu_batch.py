@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import example, given, settings, st
+from hypothesis import example, given, settings, strategies as st
 
 from fmmu_lockstep import batch_lockstep
 from repro.core.fmmu import batch as B
@@ -90,8 +90,7 @@ def test_batch_capacity_eviction(setup):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(dl) + 1)
 
 
-# pinned regression cases (replayed even without a hypothesis wheel —
-# tests/_hyp.py): same-set eviction churn across update/lookup rounds
+# pinned regression cases: same-set eviction churn across update/lookup rounds
 # (the PR-2 incremental-table seed), and a re-written dlpn read back
 # through a cold cache (the PR-4 swap CondUpdate shape)
 @example([(True, [0, 1, 2, 3], 100), (False, [3, 2, 1, 0], 0),

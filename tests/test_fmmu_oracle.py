@@ -5,7 +5,7 @@ persist completely through flush_all."""
 import random
 
 import pytest
-from _hyp import example, given, settings, st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core.fmmu.oracle import FMMUOracle
 from repro.core.fmmu.types import (COND_UPDATE, LOOKUP, NIL, Request,
@@ -188,8 +188,7 @@ def test_oracle_flush_batches_same_tvpn():
     assert o.stats["flush_blocks"] == 4
 
 
-# pinned regression cases (replayed even without a hypothesis wheel —
-# tests/_hyp.py): a CondUpdate racing an Update on one dlpn, and a
+# pinned regression cases: a CondUpdate racing an Update on one dlpn, and a
 # full-block write/readback sweep that forces a flush + reload
 @example([(1, 0, 5), (0, 0, 0), (2, 0, 9), (0, 0, 0), (1, 0, 3),
           (2, 0, 9), (0, 0, 0)], 1234)

@@ -6,8 +6,7 @@ and jaxpr-identity of the journaling-disabled path.
 
 The exhaustive truncation test enumerates offsets deterministically;
 the hypothesis property on top varies the traffic script and the cut
-fraction (pinned @example seeds replay in containers without the
-hypothesis wheel — tests/_hyp.py)."""
+fraction, with the pinned @example seeds always replayed."""
 import os
 import random
 import shutil
@@ -18,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hyp import example, given, settings, st
+from hypothesis import example, given, settings, strategies as st
 from repro.core import journal as jl
 from repro.core.faults import Crash, FaultPlane, make_plan
 from repro.core.fmmu import batch as fb
@@ -241,16 +240,16 @@ def test_torn_tail_property(seed, frac, final):
 
 
 def test_torn_tail_seeded_sweep():
-    """Seeded breadth for no-hypothesis containers: 12 scripts x 4 cut
-    fractions."""
+    """Deterministic seeded breadth beside the property: 12 scripts x 4
+    cut fractions."""
     for seed in range(12):
         for frac in (0.0, 0.33, 0.71, 1.0):
             _torn_tail_case(100 + seed, exhaustive=False, frac=frac)
 
 
 def test_torn_tail_seeded_sweep_commit_kinds():
-    """Seeded breadth over the non-default dangling commits (same
-    no-hypothesis rationale as above)."""
+    """Deterministic seeded breadth over the non-default dangling
+    commits."""
     for final in ("new3", "extend3", "retire", "swap"):
         for seed in (0, 1, 2):
             for frac in (0.0, 0.45, 0.77, 1.0):

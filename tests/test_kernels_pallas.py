@@ -81,8 +81,9 @@ def test_paged_attention_shapes(b, h, kv, d, page, maxp, dtype):
         jax.random.fold_in(k, 4), jnp.arange(nb))[:b * maxp].reshape(b, maxp)
     ctx = jnp.asarray([(maxp * page * (i + 1)) // (b + 1) + 1
                        for i in range(b)], jnp.int32)
-    out, (m, l) = pa.paged_attention(q, kp, vp, table, ctx,
-                                     return_stats=True, interpret=True)
+    out, (m, l) = pa.paged_attention(q, kp.reshape(nb, page, kv * d),
+                                     vp.reshape(nb, page, kv * d), table,
+                                     ctx, return_stats=True, interpret=True)
     want, (wm, wl) = ref.paged_attention_naive(q, kp, vp, table, ctx,
                                                return_stats=True)
     np.testing.assert_allclose(out.astype(np.float32),
@@ -101,10 +102,34 @@ def test_paged_attention_softcap():
     vp = jax.random.normal(jax.random.fold_in(k, 3), (nb, page, kv, d))
     table = jnp.arange(nb).reshape(b, maxp)
     ctx = jnp.array([17, 30])
-    out = pa.paged_attention(q, kp, vp, table, ctx, softcap=25.0,
-                             interpret=True)
+    out = pa.paged_attention(q, kp.reshape(nb, page, kv * d),
+                             vp.reshape(nb, page, kv * d), table, ctx,
+                             softcap=25.0, interpret=True)
     want = ref.paged_attention_naive(q, kp, vp, table, ctx, softcap=25.0)
     np.testing.assert_allclose(out, want, atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("kernel", ["flash", "paged"])
+def test_masked_calls_never_fall_back_from_pallas(kernel):
+    """The Pallas kernels take no segment/page masks: asking for Pallas
+    with a mask raises rather than quietly running the blocked jnp
+    lowering, which on a TPU would hide the device path."""
+    from repro.kernels import ops
+    if kernel == "flash":
+        x = jnp.zeros((1, 64, 2, 32))
+        segs = jnp.ones((1, 64), jnp.int32)
+        call = lambda impl: ops.flash_attention(
+            x, x, x, segment_ids=(segs, segs), impl=impl)
+    else:
+        q = jnp.zeros((2, 4, 32))
+        pool = jnp.zeros((8, 8, 2 * 32))
+        table = jnp.arange(8).reshape(2, 4)
+        call = lambda impl: ops.paged_attention(
+            q, pool, pool, table, jnp.array([5, 9]),
+            page_mask=jnp.ones((2, 4), bool), impl=impl)
+    with pytest.raises(NotImplementedError):
+        call("pallas_interpret")
+    assert np.isfinite(np.asarray(call("blocked"))).all()
 
 
 # ----------------------------------------------------------------------

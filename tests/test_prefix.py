@@ -21,7 +21,7 @@ from repro.paging import kv_manager as KM
 from repro.paging.kv_manager import KVPageManager
 from repro.paging.pool import BlockPool
 
-from _hyp import example, given, settings, st
+from hypothesis import example, given, settings, strategies as st
 
 pytestmark = pytest.mark.prefix
 

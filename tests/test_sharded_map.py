@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import example, given, settings, st
+from hypothesis import example, given, settings, strategies as st
 
 from fmmu_lockstep import sharded_geometries, sharded_lockstep
 from repro.core.fmmu import batch as B
@@ -72,10 +72,9 @@ def test_sharded_lockstep_degenerate_geometry():
     assert res.startswith("OK"), res
 
 
-# pinned regression seeds (replayed by tests/_hyp.py without a wheel):
-# the seed/channel pairs that first exercised duplicate-block MSHR
-# merges landing in different channels and a COND losing its race in a
-# non-owner batch position
+# pinned regression seeds: the seed/channel pairs that first exercised
+# duplicate-block MSHR merges landing in different channels and a COND
+# losing its race in a non-owner batch position
 @example(11, 2)
 @example(23, 8)
 @settings(max_examples=8, deadline=None)
