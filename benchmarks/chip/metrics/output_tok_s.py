@@ -1,0 +1,5 @@
+"""Output tokens delivered in the window over the window's seconds."""
+
+
+def read(run):
+    return run.tokens_out / run.window_s if run.window_s > 0 else None
