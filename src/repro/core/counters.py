@@ -15,10 +15,17 @@ so contract tests and the bench can treat "all counters" as a value.
 Counters are host-only instrumentation: nothing here ever enters a
 traced graph, and trace-time counters (``fmmu.probe_traces``) count
 *tracings*, not executions, exactly as before.
+
+``span(name, **args)`` is the matching host span: a profiler
+``TraceAnnotation``, so a program's spans land in the profiler's own
+host plane, on the clock of the device ops, with ``args`` as the
+event's stats. With no profiler running a span costs one cheap call.
 """
 from __future__ import annotations
 
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class Counters:
@@ -65,3 +72,12 @@ class Counters:
 # The process-wide registry. Subsystems register their cells at import
 # time (`X = COUNTERS.cell("sub.x")`) and keep bumping `X[0]` as before.
 COUNTERS = Counters()
+
+
+def span(name: str, **args):
+    """A host span ``name`` (a context manager) recorded by the profiler
+    with ``args`` as its stats. A step takes a plain annotation too: on a
+    TPU v5e, two of three traced serving runs whose steps were
+    ``StepTraceAnnotation``s stalled ~4.5 s inside one readback, and two
+    runs of the same traffic with plain annotations did not."""
+    return TraceAnnotation(name, **args)

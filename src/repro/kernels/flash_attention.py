@@ -110,6 +110,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
         bidirectional=bidirectional, q_offset=skv - sq)
     out = pl.pallas_call(
         kernel,
+        name="_fa_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, q_block, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),

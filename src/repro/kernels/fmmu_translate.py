@@ -162,6 +162,7 @@ def fmmu_translate(tags, valid, refbits, data, backing, dlpns, touch, *,
     we = n_ways * entries_per_block
     hit, dppn, set_idx, way, new_ref = pl.pallas_call(
         kernel,
+        name="_ft_kernel",
         grid=(bq_p // blk, np_p // ch),
         in_specs=[
             full, full,

@@ -84,6 +84,7 @@ def mamba_chunk_scan(x, dt, A, B, C, D, *, chunk=256, initial_state=None,
     kernel = functools.partial(_ms_kernel, chunk=chunk, has_init=has_init)
     y, fin = pl.pallas_call(
         kernel,
+        name="_ms_kernel",
         grid=(bt, h, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, 1, p), lambda b, hh, c: (b, c, hh, 0)),

@@ -104,6 +104,7 @@ def paged_attention(q, k_pool, v_pool, block_table, ctx_lens, *,
         window=window, page=page)
     out, m, l = pl.pallas_call(
         kernel,
+        name="_pa_kernel",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, maxp),

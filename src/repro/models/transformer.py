@@ -243,22 +243,26 @@ def stack_decode(params, x, caches, cfg, rt: Runtime, ctx, *, ctx_lens,
             h = common.rms_norm(xc, lp["ln1"], cfg.norm_eps)
             if cfg.layer_kind(j) == "attn":
                 ai = a_of[j]
+                # the pool's read and write-back carry the kv_pool scope
+                # (op metadata only); the attention between them does not
+                with jax.named_scope("kv_pool"):
+                    pool_k = new_cc["pool_k"][ai]
+                    pool_v = new_cc["pool_v"][ai]
                 if rt.shard_kv_pool_pages:
                     y, pk, pv = attention.attn_decode_paged_striped(
                         lp["mixer"], h, cfg, rt, ctx,
-                        pool_k=new_cc["pool_k"][ai],
-                        pool_v=new_cc["pool_v"][ai],
+                        pool_k=pool_k, pool_v=pool_v,
                         block_table=block_table, ctx_lens=ctx_lens,
                         kind=cfg.attn_kind(j))
                 else:
                     y, pk, pv = attention.attn_decode_paged(
                         lp["mixer"], h, cfg, rt,
-                        pool_k=new_cc["pool_k"][ai],
-                        pool_v=new_cc["pool_v"][ai],
+                        pool_k=pool_k, pool_v=pool_v,
                         block_table=block_table, ctx_lens=ctx_lens,
                         kind=cfg.attn_kind(j))
-                new_cc["pool_k"] = new_cc["pool_k"].at[ai].set(pk)
-                new_cc["pool_v"] = new_cc["pool_v"].at[ai].set(pv)
+                with jax.named_scope("kv_pool"):
+                    new_cc["pool_k"] = new_cc["pool_k"].at[ai].set(pk)
+                    new_cc["pool_v"] = new_cc["pool_v"].at[ai].set(pv)
             else:
                 si = s_of[j]
                 y, (cs, ss) = ssm.ssm_decode(

@@ -92,7 +92,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core import journal as jl
-from repro.core.counters import COUNTERS
+from repro.core.counters import COUNTERS, span
 from repro.core.faults import FaultPlane, SwapFault
 from repro.core.fmmu import batch as fb
 from repro.core.fmmu.types import NIL
@@ -106,10 +106,25 @@ from repro.serving.config import (DurabilityConfig, FaultPolicy,
 
 # Host-cost counters (the XLATE_CALLS pattern): one MACRO_DISPATCHES
 # bump per macro-step jit call, one HOST_SYNCS bump per blocking
-# device->host readback. tests/test_serving.py asserts steady-state
+# device->host readback on the step path (the macro token readback,
+# the prefill's first token, the single-step token row), each under a
+# ``serve.sync`` span. tests/test_serving.py asserts steady-state
 # macro decode costs exactly one of each per K steps. The names alias
 # registry cells (core/counters.py): same list objects, also visible
 # to COUNTERS.snapshot()/delta().
+#
+# Host spans (core/counters.span) of one step(), outermost first:
+#   serve.step      the whole round (step_num: the boundary count before
+#                   it)
+#   serve.admit     admission;  serve.prefill  one prefill (rid, tokens)
+#   serve.map       KV-map calls: new_seq, free_seq, sync_allocator,
+#                   reconcile_macro
+#   serve.plan      lane arrays, growth walk, page bucket
+#   serve.dispatch  the K-step scan (or single-step) jit call
+#   serve.sync      a blocking readback (HOST_SYNCS)
+#   serve.book      token bookkeeping and frees
+# The GC, swap, journal, prefix and sharded-channel paths carry no span
+# of their own.
 MACRO_DISPATCHES = COUNTERS.cell("engine.macro_dispatches")
 HOST_SYNCS = COUNTERS.cell("engine.host_syncs")
 
@@ -325,7 +340,8 @@ class ServeEngine:
                         "watchdog_quarantines": 0, "requeues": 0,
                         "recoveries": 0, "gc_walks": 0, "gc_moves": 0,
                         "gc_victims": 0, "shared_admits": 0,
-                        "shared_pages": 0, "cow_moves": 0}
+                        "shared_pages": 0, "cow_moves": 0,
+                        "host_syncs": 0}
         # crash-consistency journal (ISSUE 7, core/journal.py): when
         # attached, every host commit point appends a sequence-numbered
         # record and every `snapshot_every`-th macro boundary writes a
@@ -538,42 +554,52 @@ class ServeEngine:
         """One scheduling round: admissions (budgeted), boundary swap
         planning, then either ONE fused K-step macro-step (swap-pending
         slots masked as paused lanes) or one single decode step."""
-        self._admit()
-        if not self.active:
-            return bool(self.queue)
-        # one scheduling round = one boundary (the aging/backoff/
-        # watchdog clock); counted here so fallback rounds age too
-        self._boundary += 1
-        if self.watchdog_rounds:
-            self._watchdog()
+        with span("serve.step", step_num=self._boundary):
+            with span("serve.admit"):
+                self._admit()
             if not self.active:
                 return bool(self.queue)
-        if self._macro_on and self.nonblocking_swap:
-            self._swap_schedule()
-        # COW frontier (ISSUE 10): shared pages the coming writes
-        # would touch go private here, before any decode dispatch
-        # (and before the macro paths' allocator sync — the copies'
-        # destination pops must reach the device mirror)
-        if self.prefix is not None:
-            self._cow_boundary()
-        if self._macro_eligible():
-            self._macro_decode_step(done)
-        else:
-            if self._macro_on:
-                self.metrics["macro_fallbacks"] += 1
-            self._decode_step(done)
-        # GC watermark policy (ISSUE 9 tentpole): when any channel's
-        # free device blocks fall below the watermark, run ONE budgeted
-        # victim walk at this boundary — never inside the decode path
-        if self.gc is not None:
-            self._gc_boundary()
-        # macro-boundary snapshot cadence (ISSUE 7): every
-        # snapshot_every-th scheduling round seals the journal with a
-        # full atomic state snapshot, bounding replay length (MTTR)
-        if self.journal is not None and self.snapshot_every \
-                and self._boundary % self.snapshot_every == 0:
-            self._write_snapshot()
-        return bool(self.active or self.queue)
+            # one scheduling round = one boundary (the aging/backoff/
+            # watchdog clock); counted here so fallback rounds age too
+            self._boundary += 1
+            if self.watchdog_rounds:
+                self._watchdog()
+                if not self.active:
+                    return bool(self.queue)
+            if self._macro_on and self.nonblocking_swap:
+                self._swap_schedule()
+            # COW frontier (prefix sharing): shared pages the coming writes
+            # would touch go private here, before any decode dispatch
+            # (and before the macro paths' allocator sync — the copies'
+            # destination pops must reach the device mirror)
+            if self.prefix is not None:
+                self._cow_boundary()
+            if self._macro_eligible():
+                self._macro_decode_step(done)
+            else:
+                if self._macro_on:
+                    self.metrics["macro_fallbacks"] += 1
+                self._decode_step(done)
+            # GC watermark policy: when any channel's
+            # free device blocks fall below the watermark, run ONE budgeted
+            # victim walk at this boundary — never inside the decode path
+            if self.gc is not None:
+                self._gc_boundary()
+            # macro-boundary snapshot cadence (journal): every
+            # snapshot_every-th scheduling round seals the journal with a
+            # full atomic state snapshot, bounding replay length (MTTR)
+            if self.journal is not None and self.snapshot_every \
+                    and self._boundary % self.snapshot_every == 0:
+                self._write_snapshot()
+            return bool(self.active or self.queue)
+
+    def _sync(self):
+        """Enter around one blocking device->host readback on the step
+        path: counted once (HOST_SYNCS, ``metrics["host_syncs"]``) and
+        spanned as ``serve.sync``."""
+        HOST_SYNCS[0] += 1
+        self.metrics["host_syncs"] += 1
+        return span("serve.sync")
 
     def _free_slots(self) -> List[int]:
         used = {r.slot for r in self.active.values()}
@@ -618,7 +644,8 @@ class ServeEngine:
                 n_pages = -(-(chunk + n_prefix) // self.page)
                 n_pages = max(1, min(n_pages, self.max_pages))
             try:
-                self.kvm.new_seq(slot, n_pages, shared=shared_blocks)
+                with span("serve.map"):
+                    self.kvm.new_seq(slot, n_pages, shared=shared_blocks)
             except OutOfBlocks:
                 if not self._preempt(exclude=slot):
                     return
@@ -651,7 +678,8 @@ class ServeEngine:
                 self.metrics["shared_admits"] += 1
                 self.metrics["shared_pages"] += len(shared_blocks)
             else:
-                self._do_prefill(req, chunk)
+                with span("serve.prefill", rid=req.rid, tokens=chunk):
+                    self._do_prefill(req, chunk)
                 if budget is not None:
                     budget -= chunk
 
@@ -1091,7 +1119,8 @@ class ServeEngine:
             self.metrics["chunked_prefills"] += 1
         else:
             self._register_prompt(req)
-            tok = int(jnp.argmax(logits[0]))
+            with self._sync():
+                tok = int(jnp.argmax(logits[0]))
             req.out.append(tok)
             self.metrics["generated"] += 1
         self.metrics["prefills"] += 1
@@ -1226,10 +1255,14 @@ class ServeEngine:
         # per-step host sync is the next_tok readback
         pages = self._page_bucket(max(
             len(self.kvm.seq_pages[r.slot]) for r in residents))
-        next_tok, self.caches = self._decode(
-            self.params, tokens, self.caches, self.ctx_lens,
-            self.kvm.state.table, resident_mask, src_valid, pages)
-        self._finish_step(residents, np.asarray(next_tok), done)
+        with span("serve.dispatch"):
+            next_tok, self.caches = self._decode(
+                self.params, tokens, self.caches, self.ctx_lens,
+                self.kvm.state.table, resident_mask, src_valid, pages)
+        with self._sync():
+            next_tok = np.asarray(next_tok)
+        with span("serve.book"):
+            self._finish_step(residents, next_tok, done)
 
     # ------------------------------------------------------ macro-steps
     def _macro_fn(self, params, ms, caches, cur_tok, ctx_lens, n_pages,
@@ -1517,7 +1550,8 @@ class ServeEngine:
             if len(r.out) >= r.max_new:
                 done[r.rid] = r.out[:r.max_new]
                 self._journal_finish(r)
-                self.kvm.free_seq(s)
+                with span("serve.map"):
+                    self.kvm.free_seq(s)
                 self._release_slot(s)
                 del self.active[r.rid]
 
@@ -1538,87 +1572,93 @@ class ServeEngine:
         replay, token bookkeeping, frees."""
         if self.channels > 1:
             return self._macro_decode_step_sharded(done)
-        self.kvm.sync_allocator()      # no-op unless the pool mutated
-        # swap-pending slots stay active but are NOT in the batch: they
-        # are masked lanes until the boundary scheduler resumes them
-        residents = [r for r in self.active.values()
-                     if self.kvm.is_resident(r.slot)]
-        K = self.macro_k
-        (tokens, alive, budget, npages, pend, fmask, ftok, emit,
-         slot2req) = self._macro_lanes(residents, K)
-        # CTP (ISSUE 9): the boundary knows the next K-step growth
-        # exactly (the same mirror-protocol walk the scheduler and the
-        # reconcile replay run), so pull the backing-table segments
-        # those dlpns live in into the CMT AHEAD of the scan's
-        # in-graph UPDATE commits
-        if self.gc is not None and self.gc.prefetch and residents:
-            pgs, pdl, _ = self._growth_walk(lambda k: alive, npages,
-                                            self.ctx_lens)
-            if pgs.any():
-                self.kvm.prefetch_segments(pdl[pgs])
-        src_valid = self._src_valid()
-        # the `simple` specialization applies when no lane can finish
-        # mid-scan: without EOS the retirement machinery is dead weight
-        # on every scan step. A forced lane only emits K - (P-1) tokens
-        # during the scan, so its budget needs to cover just that.
-        gen = K - np.maximum(pend - 1, 0)
-        simple = self.eos_id < 0 and bool(
-            (budget[alive] >= gen[alive]).all())
-        if simple:
-            # precompute the growth schedule the scan will follow (no
-            # retirement ⟹ the live set is static ⟹ page crossings
-            # are a pure function of ctx/pages the host already holds)
-            grow_sched, dl_sched, npages = self._growth_walk(
-                lambda k: alive, npages, self.ctx_lens)
-            sched = (grow_sched, grow_sched.any(axis=1), dl_sched)
-        # live-page bucket: worst-case pages any slot can hold by scan
-        # end (exact post-schedule count in simple mode)
-        if simple:
-            pages = self._page_bucket(int(npages[alive].max()))
-        else:
-            end = np.minimum(
-                self.max_pages,
-                np.maximum(npages, (self.ctx_lens + self.macro_k
-                                    + self.page - 1) // self.page))
-            pages = self._page_bucket(int(end[alive].max()))
+        with span("serve.map"):
+            self.kvm.sync_allocator()   # no-op unless the pool mutated
+        with span("serve.plan"):
+            # swap-pending slots stay active but are NOT in the batch: they
+            # are masked lanes until the boundary scheduler resumes them
+            residents = [r for r in self.active.values()
+                         if self.kvm.is_resident(r.slot)]
+            K = self.macro_k
+            (tokens, alive, budget, npages, pend, fmask, ftok, emit,
+             slot2req) = self._macro_lanes(residents, K)
+            # CTP (GC prefetch): the boundary knows the next K-step growth
+            # exactly (the same mirror-protocol walk the scheduler and the
+            # reconcile replay run), so pull the backing-table segments
+            # those dlpns live in into the CMT AHEAD of the scan's
+            # in-graph UPDATE commits
+            if self.gc is not None and self.gc.prefetch and residents:
+                pgs, pdl, _ = self._growth_walk(lambda k: alive, npages,
+                                                self.ctx_lens)
+                if pgs.any():
+                    self.kvm.prefetch_segments(pdl[pgs])
+            src_valid = self._src_valid()
+            # the `simple` specialization applies when no lane can finish
+            # mid-scan: without EOS the retirement machinery is dead weight
+            # on every scan step. A forced lane only emits K - (P-1) tokens
+            # during the scan, so its budget needs to cover just that.
+            gen = K - np.maximum(pend - 1, 0)
+            simple = self.eos_id < 0 and bool(
+                (budget[alive] >= gen[alive]).all())
+            if simple:
+                # precompute the growth schedule the scan will follow (no
+                # retirement ⟹ the live set is static ⟹ page crossings
+                # are a pure function of ctx/pages the host already holds)
+                grow_sched, dl_sched, npages = self._growth_walk(
+                    lambda k: alive, npages, self.ctx_lens)
+                sched = (grow_sched, grow_sched.any(axis=1), dl_sched)
+            # live-page bucket: worst-case pages any slot can hold by scan
+            # end (exact post-schedule count in simple mode)
+            if simple:
+                pages = self._page_bucket(int(npages[alive].max()))
+            else:
+                end = np.minimum(
+                    self.max_pages,
+                    np.maximum(npages, (self.ctx_lens + self.macro_k
+                                        + self.page - 1) // self.page))
+                pages = self._page_bucket(int(end[alive].max()))
         MACRO_DISPATCHES[0] += 1
         # steady state (no lane mid-prompt) uses the forced=None trace:
         # the scan carries zero admission machinery
         forced = (fmask, ftok, emit) if pend.any() else None
-        st, self.caches, toks, oob = (
-            self._macro_simple(
-                self.params, self.kvm.state, self.caches, tokens,
-                self.ctx_lens, sched, alive, budget, forced, src_valid,
-                pages)
-            if simple else
-            self._macro(
-                self.params, self.kvm.state, self.caches, tokens,
-                self.ctx_lens, npages, alive, budget, forced, src_valid,
-                pages))
+        with span("serve.dispatch"):
+            st, self.caches, toks, oob = (
+                self._macro_simple(
+                    self.params, self.kvm.state, self.caches, tokens,
+                    self.ctx_lens, sched, alive, budget, forced, src_valid,
+                    pages)
+                if simple else
+                self._macro(
+                    self.params, self.kvm.state, self.caches, tokens,
+                    self.ctx_lens, npages, alive, budget, forced, src_valid,
+                    pages))
         self.kvm.state = st
-        HOST_SYNCS[0] += 1
-        toks, oob = jax.device_get((toks, oob))
+        with self._sync():
+            toks, oob = jax.device_get((toks, oob))
         self.metrics["macro_steps"] += 1
-        if simple:
-            # np.nonzero on [K,S] is row-major == the scan's step-major
-            # slot-ascending pop order
-            grow_seq = [int(s) for s in np.nonzero(grow_sched)[1]]
-        else:
-            # NIL marks lanes that emitted nothing (retired/paused);
-            # replay the scan's growth decisions (the same _growth_walk
-            # arithmetic, gated on the scan's own live mask) to recover
-            # the allocation sequence — the allocator mirror makes the
-            # popped block ids predictable, so no log left the device
-            valid = (toks >= 0) & alive[None, :]
-            grew, _, npages = self._growth_walk(
-                lambda k: valid[k], npages, self.ctx_lens)
-            grow_seq = [int(s) for s in np.nonzero(grew)[1]]
-        got = self.kvm.reconcile_macro(grow_seq)
-        self._retire_macro_programs(grow_seq, got)
-        if simple:
-            self._macro_book_simple(residents, toks, pend, K, done)
-        else:
-            self._macro_book_full(valid, toks, slot2req, done)
+        with span("serve.plan"):
+            if simple:
+                # np.nonzero on [K,S] is row-major == the scan's step-major
+                # slot-ascending pop order
+                grow_seq = [int(s) for s in np.nonzero(grow_sched)[1]]
+            else:
+                # NIL marks lanes that emitted nothing (retired/paused);
+                # replay the scan's growth decisions (the same _growth_walk
+                # arithmetic, gated on the scan's own live mask) to recover
+                # the allocation sequence — the allocator mirror makes the
+                # popped block ids predictable, so no log left the device
+                valid = (toks >= 0) & alive[None, :]
+                grew, _, npages = self._growth_walk(
+                    lambda k: valid[k], npages, self.ctx_lens)
+                grow_seq = [int(s) for s in np.nonzero(grew)[1]]
+        with span("serve.map"):
+            got = self.kvm.reconcile_macro(grow_seq)
+            self._retire_macro_programs(grow_seq, got)
+        with span("serve.book"):
+            if simple:
+                self._macro_book_simple(residents, toks, pend, K, done)
+            else:
+                self._macro_book_full(valid, toks, slot2req, done)
         if oob:
             # the proactive check makes this unreachable without a
             # fault plane; fold the flag into the typed per-channel
@@ -1785,8 +1825,8 @@ class ServeEngine:
             self.caches, toks = self._macro_sh(
                 self.params, self.caches, self.kvm.state.table, tokens,
                 self.ctx_lens, alive, budget, forced, src_valid, pages)
-        HOST_SYNCS[0] += 1
-        toks = jax.device_get(toks)
+        with self._sync():
+            toks = jax.device_get(toks)
         self.metrics["macro_steps"] += 1
         if simple:
             self._macro_book_simple(residents, toks, pend, K, done)
@@ -1813,7 +1853,8 @@ class ServeEngine:
             if len(r.out) >= r.max_new or tok == self.eos_id:
                 done[r.rid] = r.out[:r.max_new]
                 self._journal_finish(r)
-                self.kvm.free_seq(r.slot)
+                with span("serve.map"):
+                    self.kvm.free_seq(r.slot)
                 self._release_slot(r.slot)
                 del self.active[r.rid]
 

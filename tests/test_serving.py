@@ -8,6 +8,7 @@ import pytest
 
 from repro.configs import get_arch, smoke_config
 from repro.models import Runtime, build_model
+from repro.serving.config import ServeConfig
 from repro.serving.engine import ServeEngine
 
 RT = Runtime(compute_dtype=jnp.float32, param_dtype=jnp.float32,
@@ -404,3 +405,89 @@ def test_steady_state_decode_zero_full_map_translations():
             assert B.PROBE_TRACES[0] - p0 == 0
     assert boundary_seen, "bench window never crossed a page boundary"
     assert eng.metrics["decode_steps"] >= 14
+
+
+@pytest.mark.parametrize("macro_k", [1, 4])
+def test_admission_costs_one_host_sync(macro_k):
+    """Every blocking readback on the step path is one HOST_SYNCS bump:
+    a step that admits one request pays its prefill's first-token
+    readback plus the decode readback (macro scan or single step), and
+    a steady step pays the decode readback alone. The engine's own
+    ``host_syncs`` count moves with the registry cell."""
+    from repro.serving import engine as E
+    cfg = smoke_config(get_arch("llama3.2-1b"))
+    m = build_model(cfg, RT)
+    params = m.init(jax.random.key(0))
+    eng = ServeEngine(m, params, config=ServeConfig(
+        n_slots=2, max_ctx=64, macro_k=macro_k))
+    done: dict = {}
+    for n_admit in (1, 0, 1, 0):
+        if n_admit:
+            eng.submit(list(range(1, 9)), max_new=32)
+        s0, e0, p0 = (E.HOST_SYNCS[0], eng.metrics["host_syncs"],
+                      eng.metrics["prefills"])
+        eng.step(done)
+        assert eng.metrics["prefills"] - p0 == n_admit
+        assert E.HOST_SYNCS[0] - s0 == n_admit + 1
+        assert eng.metrics["host_syncs"] - e0 == n_admit + 1
+
+
+def _serve_spans(trace_dir):
+    """(name, start_ns, end_ns, stats) of every ``serve.*`` host span in
+    the profile written under ``trace_dir``, in start order."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    out = []
+    for path in glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                          recursive=True):
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("serve."):
+                        out.append((ev.name, ev.start_ns, ev.end_ns,
+                                    {k: str(v) for k, v in ev.stats}))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def test_step_span_tree(tmp_path):
+    """Under the profiler (CPU here) each step() is one serve.step
+    annotation; admission nests serve.admit > serve.prefill (carrying
+    the request id and its token count) > serve.sync, and every macro
+    step dispatches its scan before it reads the tokens back."""
+    cfg = smoke_config(get_arch("llama3.2-1b"))
+    m = build_model(cfg, RT)
+    params = m.init(jax.random.key(0))
+    eng = ServeEngine(m, params, config=ServeConfig(
+        n_slots=2, max_ctx=64, macro_k=4))
+    done: dict = {}
+    eng.submit(list(range(1, 9)), max_new=32)
+    eng.step(done)                     # compile outside the trace
+    rid = eng.submit(list(range(20, 31)), max_new=32)
+    n_steps = 3
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(n_steps):
+            eng.step(done)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _serve_spans(str(tmp_path))
+
+    def inside(name, outer):
+        return [s for s in spans if s[0] == name
+                and outer[1] <= s[1] and s[2] <= outer[2]]
+
+    steps = [s for s in spans if s[0] == "serve.step"]
+    assert len(steps) == n_steps
+    assert [int(s[3]["step_num"]) for s in steps] == [1, 2, 3]
+    (admit,) = [a for s in steps for a in inside("serve.admit", s)
+                if inside("serve.prefill", a)]
+    (prefill,) = inside("serve.prefill", admit)
+    assert prefill[3]["rid"] == str(rid)
+    assert prefill[3]["tokens"] == "11"
+    assert len(inside("serve.sync", prefill)) == 1
+    for s in steps:
+        (dispatch,) = inside("serve.dispatch", s)
+        syncs = [x for x in inside("serve.sync", s) if x[1] >= dispatch[2]]
+        assert len(syncs) == 1
+        assert inside("serve.book", s) and inside("serve.map", s)
