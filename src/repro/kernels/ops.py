@@ -58,10 +58,11 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
 
 # ----------------------------------------------------------------------
 def paged_attention(q, k_pool, v_pool, block_table, ctx_lens, *,
-                    softcap=0.0, window=0, page_mask=None,
+                    layer=None, softcap=0.0, window=0, page_mask=None,
                     return_stats=False, impl=None, pages_per_chunk=None):
     """q [B,H,D]; lane-dense pools [NB,P,KV*D] (kernels/paged_attention
-    .py says why); the jnp lowerings view them as [NB,P,KV,D]."""
+    .py says why), or the stack's [L,NB,P,KV*D] with ``layer`` the one
+    to read; the jnp lowerings view them as [.., NB,P,KV,D]."""
     sel = _default_impl(impl)
     if sel in ("pallas", "pallas_interpret") and page_mask is not None:
         raise NotImplementedError(
@@ -70,13 +71,13 @@ def paged_attention(q, k_pool, v_pool, block_table, ctx_lens, *,
     if sel in ("pallas", "pallas_interpret"):
         from repro.kernels import paged_attention as pa
         return pa.paged_attention(
-            q, k_pool, v_pool, block_table, ctx_lens, softcap=softcap,
-            window=window, return_stats=return_stats,
+            q, k_pool, v_pool, block_table, ctx_lens, layer=layer,
+            softcap=softcap, window=window, return_stats=return_stats,
             interpret=(sel == "pallas_interpret"))
-    nb, p, kvd = k_pool.shape
+    *lead, p, kvd = k_pool.shape
     d = q.shape[-1]
-    k_pool = k_pool.reshape(nb, p, kvd // d, d)
-    v_pool = v_pool.reshape(nb, p, kvd // d, d)
+    k_pool = k_pool.reshape(*lead, p, kvd // d, d)
+    v_pool = v_pool.reshape(*lead, p, kvd // d, d)
     if sel == "blocked":
         if pages_per_chunk is None:
             # auto: chunking bounds live memory at O(c * P) per (B,H),
@@ -86,11 +87,11 @@ def paged_attention(q, k_pool, v_pool, block_table, ctx_lens, *,
             maxp = block_table.shape[1]
             pages_per_chunk = maxp if maxp * p <= 1024 else 8
         return ref.paged_attention_blocked(
-            q, k_pool, v_pool, block_table, ctx_lens, softcap=softcap,
-            window=window, page_mask=page_mask,
+            q, k_pool, v_pool, block_table, ctx_lens, layer=layer,
+            softcap=softcap, window=window, page_mask=page_mask,
             pages_per_chunk=pages_per_chunk, return_stats=return_stats)
     return ref.paged_attention_naive(q, k_pool, v_pool, block_table,
-                                     ctx_lens, softcap=softcap,
+                                     ctx_lens, layer=layer, softcap=softcap,
                                      window=window, page_mask=page_mask,
                                      return_stats=return_stats)
 

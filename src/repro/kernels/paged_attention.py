@@ -18,6 +18,12 @@ head h // group and zeros elsewhere — so one [H, KV*D] x [KV*D, P]
 matmul gives every head's scores without an in-kernel reshape; the
 [H, KV*D] output is cut back to each head's own kv columns outside.
 
+The pool may also be the whole stack's, [L, NB, P, KV*D], with the
+layer to read as a third scalar-prefetch operand: the decode scan
+carries every layer's pool and hands the kernel the stack, so no
+layer's pool is ever sliced out (copied) for the call. A single-layer
+pool is the stack of one.
+
 Grid = (batch, n_pages); online-softmax stats carried in VMEM scratch
 across the page axis; per-sequence length masking from a prefetched
 ctx_lens vector. Returns optional (m, l) stats for the cross-shard
@@ -36,8 +42,9 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _pa_kernel(table_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
-               l_ref, macc, lacc, acc, *, scale, softcap, window, page):
+def _pa_kernel(table_ref, ctx_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
+               m_ref, l_ref, macc, lacc, acc, *, scale, softcap, window,
+               page):
     b = pl.program_id(0)
     i = pl.program_id(1)
     np_ = pl.num_programs(1)
@@ -87,12 +94,16 @@ def _pa_kernel(table_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
 
 
 def paged_attention(q, k_pool, v_pool, block_table, ctx_lens, *,
-                    softcap=0.0, window=0, return_stats=False,
+                    layer=None, softcap=0.0, window=0, return_stats=False,
                     interpret=False):
-    """q [B,H,D]; pools [NB,P,KV*D]; block_table [B,MAXP] int32;
-    ctx_lens [B] int32 -> [B,H,D] (+ (m,l) [B,H] fp32)."""
+    """q [B,H,D]; pools [NB,P,KV*D], or [L,NB,P,KV*D] with ``layer`` the
+    one to read; block_table [B,MAXP] int32; ctx_lens [B] int32
+    -> [B,H,D] (+ (m,l) [B,H] fp32)."""
+    if layer is None:
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     b, h, d = q.shape
-    nb, page, kvd = k_pool.shape
+    _, nb, page, kvd = k_pool.shape
     kv = kvd // d
     maxp = block_table.shape[1]
     head_kv = jnp.arange(h) // (h // kv)
@@ -102,25 +113,26 @@ def paged_attention(q, k_pool, v_pool, block_table, ctx_lens, *,
     kernel = functools.partial(
         _pa_kernel, scale=1.0 / math.sqrt(d), softcap=softcap,
         window=window, page=page)
+    per_b = lambda bi, i, tbl, ctx, ly: (bi, 0, 0)
+    page_of = lambda bi, i, tbl, ctx, ly: (ly[0], tbl[bi, i], 0, 0)
     out, m, l = pl.pallas_call(
         kernel,
         name="_pa_kernel",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b, maxp),
             in_specs=[
-                pl.BlockSpec((1, h, kvd), lambda bi, i, tbl, ctx: (bi, 0, 0)),
-                pl.BlockSpec((1, page, kvd),
-                             lambda bi, i, tbl, ctx: (tbl[bi, i], 0, 0)),
-                pl.BlockSpec((1, page, kvd),
-                             lambda bi, i, tbl, ctx: (tbl[bi, i], 0, 0)),
+                pl.BlockSpec((1, h, kvd), per_b),
+                # the layer axis is squeezed: the body sees [1, P, KV*D]
+                pl.BlockSpec((None, 1, page, kvd), page_of),
+                pl.BlockSpec((None, 1, page, kvd), page_of),
             ],
             # stats are [B, H, 1]: the last two block dims must be
             # (8, 128)-aligned or span the array, which (1, H) is not
             out_specs=[
-                pl.BlockSpec((1, h, kvd), lambda bi, i, tbl, ctx: (bi, 0, 0)),
-                pl.BlockSpec((1, h, 1), lambda bi, i, tbl, ctx: (bi, 0, 0)),
-                pl.BlockSpec((1, h, 1), lambda bi, i, tbl, ctx: (bi, 0, 0)),
+                pl.BlockSpec((1, h, kvd), per_b),
+                pl.BlockSpec((1, h, 1), per_b),
+                pl.BlockSpec((1, h, 1), per_b),
             ],
             scratch_shapes=[
                 pltpu.VMEM((h, 1), jnp.float32),
@@ -134,7 +146,7 @@ def paged_attention(q, k_pool, v_pool, block_table, ctx_lens, *,
             jax.ShapeDtypeStruct((b, h, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(block_table, ctx_lens, q_bd, k_pool, v_pool)
+    )(block_table, ctx_lens, layer, q_bd, k_pool, v_pool)
     out = out.reshape(b, h, kv, d)[:, jnp.arange(h), head_kv]   # [B, H, D]
     if return_stats:
         return out, (m[..., 0], l[..., 0])

@@ -189,26 +189,31 @@ def flash_attention_blocked(q, k, v, *, causal=True, window=0, softcap=0.0,
 # ======================================================================
 # Paged decode attention
 # ======================================================================
+def _pages(pool, layer, pages):
+    """pool[pages], or pool[layer, pages] of a stacked [L, NB, ..] pool:
+    the gather reads the named pages alone, never a whole layer."""
+    return pool[pages] if layer is None else pool[layer, pages]
+
+
 def paged_attention_naive(q, k_pool, v_pool, block_table, ctx_lens, *,
-                          softcap=0.0, window=0, page_mask=None,
+                          layer=None, softcap=0.0, window=0, page_mask=None,
                           return_stats=False):
     """One-token decode attention over a paged KV pool.
 
     q           [B, H, D]
-    k/v_pool    [NB, P, KV, D]   physical blocks (pages of P tokens)
+    k/v_pool    [NB, P, KV, D]   physical blocks (pages of P tokens), or
+                [L, NB, P, KV, D] with ``layer`` the one to read
     block_table [B, MAXP] int32  logical page i of seq b -> physical block
     ctx_lens    [B] int32        tokens of context (including none of q)
     returns     [B, H, D]  (+ (m, l) fp32 stats if return_stats, for
                             cross-shard flash-decoding combine)
     """
     b, h, d = q.shape
-    nb, p, kv, _ = k_pool.shape
+    p, kv, _ = k_pool.shape[-3:]
     maxp = block_table.shape[1]
-    kg = k_pool.astype(jnp.float32)
-    vg = v_pool.astype(jnp.float32)
     # gather pages: [B, MAXP, P, KV, D]
-    kseq = kg[block_table]
-    vseq = vg[block_table]
+    kseq = _pages(k_pool, layer, block_table).astype(jnp.float32)
+    vseq = _pages(v_pool, layer, block_table).astype(jnp.float32)
     kseq = kseq.reshape(b, maxp * p, kv, d)
     vseq = vseq.reshape(b, maxp * p, kv, d)
     kseq = _group_kv(kseq, h)
@@ -233,12 +238,13 @@ def paged_attention_naive(q, k_pool, v_pool, block_table, ctx_lens, *,
 
 
 def paged_attention_blocked(q, k_pool, v_pool, block_table, ctx_lens, *,
-                            softcap=0.0, window=0, page_mask=None,
+                            layer=None, softcap=0.0, window=0, page_mask=None,
                             pages_per_chunk=8, return_stats=False):
     """Flash-decoding style: scan over page chunks with online softmax.
-    Live memory O(pages_per_chunk * P) per (B,H)."""
+    Live memory O(pages_per_chunk * P) per (B,H). Pools as for
+    paged_attention_naive."""
     b, h, d = q.shape
-    nb, p, kv, _ = k_pool.shape
+    p, kv, _ = k_pool.shape[-3:]
     maxp = block_table.shape[1]
     c = min(pages_per_chunk, maxp)
     if maxp % c:
@@ -254,8 +260,9 @@ def paged_attention_blocked(q, k_pool, v_pool, block_table, ctx_lens, *,
             pages = lax.dynamic_slice_in_dim(table_b, ci * c, c, 0)   # [c]
             pm = (lax.dynamic_slice_in_dim(pmask_b, ci * c, c, 0)
                   if pmask_b is not None else None)
-            kc = k_pool[pages].astype(jnp.float32)    # [c, P, KV, D]
-            vc = v_pool[pages].astype(jnp.float32)
+            # [c, P, KV, D] each
+            kc = _pages(k_pool, layer, pages).astype(jnp.float32)
+            vc = _pages(v_pool, layer, pages).astype(jnp.float32)
             kc = kc.reshape(c * p, kv, d)
             vc = vc.reshape(c * p, kv, d)
             pos = ci * (c * p) + jnp.arange(c * p)

@@ -123,32 +123,40 @@ def cross_kv(params, enc_out, cfg, rt: Runtime):
 # Paged decode
 # ----------------------------------------------------------------------
 def write_kv_page(pool_k, pool_v, k_new, v_new, block_table, ctx_lens,
-                  page_size: int):
-    """Scatter one new token's K/V into the paged pool.
-    pools [NB,P,KV*hd]; k_new/v_new [B,KV,hd]; returns updated pools."""
+                  page_size: int, layer=None):
+    """Scatter one new token's K/V into the paged pool, in place.
+    pools [NB,P,KV*hd], or the stack's [L,NB,P,KV*hd] with ``layer`` the
+    one to write; k_new/v_new [B,KV,hd]; returns updated pools. Only
+    the B new rows are written, never a whole layer (``kv_pool``
+    scope)."""
     b = k_new.shape[0]
     k_new = k_new.reshape(b, -1)
     v_new = v_new.reshape(b, -1)
     logical = ctx_lens // page_size
     offs = ctx_lens % page_size
     pages = block_table[jnp.arange(b), logical]
-    pool_k = pool_k.at[pages, offs].set(k_new.astype(pool_k.dtype))
-    pool_v = pool_v.at[pages, offs].set(v_new.astype(pool_v.dtype))
+    at = (pages, offs) if layer is None else (layer, pages, offs)
+    with jax.named_scope("kv_pool"):
+        pool_k = pool_k.at[at].set(k_new.astype(pool_k.dtype))
+        pool_v = pool_v.at[at].set(v_new.astype(pool_v.dtype))
     return pool_k, pool_v
 
 
 def attn_decode_paged(params, x, cfg, rt: Runtime, *, pool_k, pool_v,
-                      block_table, ctx_lens, kind="global",
+                      block_table, ctx_lens, kind="global", layer=None,
                       return_stats=False):
-    """One-token decode. x [B,d]; pools [NB,P,KV*hd]; returns
-    (y [B,d], pool_k, pool_v) (+ (m,l) stats for cross-shard combine)."""
+    """One-token decode. x [B,d]; pools [NB,P,KV*hd], or the stack's
+    [L,NB,P,KV*hd] with ``layer`` the one this call reads and writes;
+    returns (y [B,d], pool_k, pool_v) (+ (m,l) stats for cross-shard
+    combine)."""
     positions = ctx_lens[:, None]                      # [B,1]
     q, k, v = _project_qkv(params, x[:, None, :], cfg, rt, positions)
     pool_k, pool_v = write_kv_page(pool_k, pool_v, k[:, 0], v[:, 0],
-                                   block_table, ctx_lens, rt.page_size)
+                                   block_table, ctx_lens, rt.page_size,
+                                   layer)
     window = cfg.sliding_window if kind == "local" else 0
     res = ops.paged_attention(
-        q[:, 0], pool_k, pool_v, block_table, ctx_lens + 1,
+        q[:, 0], pool_k, pool_v, block_table, ctx_lens + 1, layer=layer,
         softcap=cfg.attn_softcap, window=window,
         return_stats=return_stats, impl=rt.kernel_impl,
         pages_per_chunk=rt.paged_chunk)

@@ -228,41 +228,56 @@ def stack_decode(params, x, caches, cfg, rt: Runtime, ctx, *, ctx_lens,
                  block_table, src_valid=None):
     """One decode step through the stack.
     x [B,d]; caches from init_decode_caches (pools already filled by
-    prefill); block_table [B, MAXP] shared across layers."""
+    prefill); block_table [B, MAXP] shared across layers.
+
+    The KV pools ride in the layer scan's carry, seen as
+    [n_periods * n_attn, NB, P, KV*hd] (merging the two leading axes is
+    a bitcast): each attention layer scatters its token's rows into the
+    carried stack in place and paged attention reads the stack through
+    the layer's index, so no layer's pool is sliced out or written back
+    whole, and a scan of decode steps around this one keeps the pool in
+    one buffer. The per-lane caches (conv, ssm, cross) are small and
+    stay in the scan's xs / ys."""
     period = cfg.period
+    n_periods = cfg.n_layers // period
     attn_js = [j for j in range(period) if cfg.layer_kind(j) == "attn"]
     ssm_js = [j for j in range(period) if cfg.layer_kind(j) == "mamba"]
     a_of = {j: i for i, j in enumerate(attn_js)}
     s_of = {j: i for i, j in enumerate(ssm_js)}
+    pools = {k: caches[k].reshape(-1, *caches[k].shape[2:])
+             for k in ("pool_k", "pool_v") if k in caches}
+    small = {k: v for k, v in caches.items() if k not in pools}
 
-    def body(xc, scanned):
-        pp, cc = scanned
-        new_cc = dict(cc)
+    def body(carry, scanned):
+        xc, pools = carry
+        pidx, pp, cc = scanned
+        pools, new_cc = dict(pools), dict(cc)
         for j in range(period):
             lp = pp[j]
             h = common.rms_norm(xc, lp["ln1"], cfg.norm_eps)
             if cfg.layer_kind(j) == "attn":
-                ai = a_of[j]
-                # the pool's read and write-back carry the kv_pool scope
-                # (op metadata only); the attention between them does not
-                with jax.named_scope("kv_pool"):
-                    pool_k = new_cc["pool_k"][ai]
-                    pool_v = new_cc["pool_v"][ai]
+                li = pidx * len(attn_js) + a_of[j]
                 if rt.shard_kv_pool_pages:
+                    # the striped kernel runs under shard_map on one
+                    # layer's pool: slice it and write it back
+                    with jax.named_scope("kv_pool"):
+                        pool_k = pools["pool_k"][li]
+                        pool_v = pools["pool_v"][li]
                     y, pk, pv = attention.attn_decode_paged_striped(
                         lp["mixer"], h, cfg, rt, ctx,
                         pool_k=pool_k, pool_v=pool_v,
                         block_table=block_table, ctx_lens=ctx_lens,
                         kind=cfg.attn_kind(j))
+                    with jax.named_scope("kv_pool"):
+                        pools["pool_k"] = pools["pool_k"].at[li].set(pk)
+                        pools["pool_v"] = pools["pool_v"].at[li].set(pv)
                 else:
-                    y, pk, pv = attention.attn_decode_paged(
-                        lp["mixer"], h, cfg, rt,
-                        pool_k=pool_k, pool_v=pool_v,
-                        block_table=block_table, ctx_lens=ctx_lens,
-                        kind=cfg.attn_kind(j))
-                with jax.named_scope("kv_pool"):
-                    new_cc["pool_k"] = new_cc["pool_k"].at[ai].set(pk)
-                    new_cc["pool_v"] = new_cc["pool_v"].at[ai].set(pv)
+                    y, pools["pool_k"], pools["pool_v"] = \
+                        attention.attn_decode_paged(
+                            lp["mixer"], h, cfg, rt,
+                            pool_k=pools["pool_k"], pool_v=pools["pool_v"],
+                            block_table=block_table, ctx_lens=ctx_lens,
+                            kind=cfg.attn_kind(j), layer=li)
             else:
                 si = s_of[j]
                 y, (cs, ss) = ssm.ssm_decode(
@@ -293,17 +308,19 @@ def stack_decode(params, x, caches, cfg, rt: Runtime, ctx, *, ctx_lens,
                 if cfg.post_norms:
                     y2 = common.rms_norm(y2, lp["post2"], cfg.norm_eps)
                 xc = xc + y2
-        return xc, new_cc
+        return (xc, pools), new_cc
 
     if rt.scan_layers:
-        x, new_caches = jax.lax.scan(body, x, (params, caches))
+        (x, pools), small = jax.lax.scan(
+            body, (x, pools),
+            (jnp.arange(n_periods, dtype=jnp.int32), params, small))
     else:
-        n_periods = cfg.n_layers // period
         outs = []
         for pidx in range(n_periods):
             pp = jax.tree.map(lambda t: t[pidx], params)
-            cc = jax.tree.map(lambda t: t[pidx], caches)
-            x, ncc = body(x, (pp, cc))
+            cc = jax.tree.map(lambda t: t[pidx], small)
+            (x, pools), ncc = body((x, pools), (pidx, pp, cc))
             outs.append(ncc)
-        new_caches = common.tree_stack(outs)
-    return x, new_caches
+        small = common.tree_stack(outs)
+    return x, {**small,
+               **{k: v.reshape(caches[k].shape) for k, v in pools.items()}}

@@ -65,11 +65,14 @@ def test_flash_attention_unaligned_seq():
 
 
 # ----------------------------------------------------------------------
-@pytest.mark.parametrize("b,h,kv,d,page,maxp", [
+PAGED_SHAPES = [
     (2, 4, 4, 32, 16, 8),
     (3, 8, 2, 64, 8, 6),      # GQA
     (1, 4, 1, 128, 32, 4),
-])
+]
+
+
+@pytest.mark.parametrize("b,h,kv,d,page,maxp", PAGED_SHAPES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_shapes(b, h, kv, d, page, maxp, dtype):
     k = jax.random.key(3)
@@ -91,6 +94,47 @@ def test_paged_attention_shapes(b, h, kv, d, page, maxp, dtype):
                                atol=TOL[dtype], rtol=TOL[dtype])
     np.testing.assert_allclose(m, wm, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(l, wl, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("b,h,kv,d,page,maxp", PAGED_SHAPES)
+@pytest.mark.parametrize("which", ["first", "middle", "last"])
+def test_paged_attention_stacked_pool(b, h, kv, d, page, maxp, which):
+    """The whole stack's pool [L, NB, P, KV*D] with a layer index (a
+    scalar-prefetch operand of the kernel, a direct pool[layer, table]
+    gather in the jnp lowerings) reads exactly that layer: the kernel
+    matches both references given the same index, and each path gives
+    bit for bit what it gives on that layer's pool alone."""
+    from repro.kernels import ops
+    n_layers = 3
+    li = {"first": 0, "middle": 1, "last": n_layers - 1}[which]
+    k = jax.random.key(5)
+    nb = b * maxp + 4
+    q = jax.random.normal(jax.random.fold_in(k, 1), (b, h, d))
+    kp = jax.random.normal(jax.random.fold_in(k, 2),
+                           (n_layers, nb, page, kv * d))
+    vp = jax.random.normal(jax.random.fold_in(k, 3),
+                           (n_layers, nb, page, kv * d))
+    table = jax.random.permutation(
+        jax.random.fold_in(k, 4), jnp.arange(nb))[:b * maxp].reshape(b, maxp)
+    ctx = jnp.asarray([(maxp * page * (i + 1)) // (b + 1) + 1
+                       for i in range(b)], jnp.int32)
+    got, alone = {}, {}
+    for impl in ("pallas_interpret", "blocked", "naive"):
+        got[impl], _ = jax.jit(lambda q, kp, vp, t, c, li: ops.paged_attention(
+            q, kp, vp, t, c, layer=li, return_stats=True,
+            impl=impl))(q, kp, vp, table, ctx, jnp.int32(li))
+        alone[impl], _ = ops.paged_attention(
+            q, kp[li], vp[li], table, ctx, return_stats=True, impl=impl)
+        np.testing.assert_array_equal(got[impl], alone[impl])
+    want = ref.paged_attention_naive(
+        q, kp[li].reshape(nb, page, kv, d), vp[li].reshape(nb, page, kv, d),
+        table, ctx)
+    for impl in got:
+        np.testing.assert_allclose(got[impl], want, atol=2e-5, rtol=2e-5)
+    # another layer's pages give another answer: the index is not ignored
+    other = ops.paged_attention(q, kp[li - 1], vp[li - 1], table, ctx,
+                                impl="naive")
+    assert not np.allclose(got["pallas_interpret"], other, atol=1e-3)
 
 
 def test_paged_attention_softcap():

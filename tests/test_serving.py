@@ -209,6 +209,43 @@ def test_macro_step_equivalence_bitwise():
         np.asarray(em.kvm.pool._free_dev, np.int32))
 
 
+@pytest.mark.parametrize("arch", ["llama3.2-1b", "gemma2-9b"])
+def test_macro_step_writes_only_its_tokens_rows(arch):
+    """One K-step macro scan changes the KV pools at exactly the rows its
+    live lanes wrote: for each attention layer l (period p, attention
+    index a: l = p * n_attn + a, gemma2 holding two per period) and each
+    token the scan decodes at position t of slot s, row (l, block of t
+    in s's table, t % page) of both pools; nothing else. A token written
+    into the wrong layer, or a layer written back whole, shows here."""
+    cfg = smoke_config(get_arch(arch))
+    m = build_model(cfg, RT)
+    params = m.init(jax.random.key(0))
+    K = 4
+    eng = ServeEngine(m, params, config=ServeConfig(
+        n_slots=2, max_ctx=64, macro_k=K))
+    eng.submit(list(range(1, 8)), max_new=40)      # crosses a page
+    eng.submit(list(range(50, 62)), max_new=40)
+    done: dict = {}
+    eng.step(done)                       # admission, prefill, a scan
+    before = {k: np.asarray(eng.caches[k]) for k in ("pool_k", "pool_v")}
+    ctx0 = eng.ctx_lens.copy()
+    n0, s0 = eng.metrics["decode_steps"], eng.metrics["macro_steps"]
+    eng.step(done)
+    assert eng.metrics["macro_steps"] - s0 == 1
+    assert eng.metrics["decode_steps"] - n0 == K
+    assert list(eng.ctx_lens - ctx0) == [K, K]
+    table = np.asarray(eng.kvm.block_tables())
+    n_per, n_attn = before["pool_k"].shape[:2]
+    want = {(p, a, int(table[s, t // eng.page]), t % eng.page)
+            for p in range(n_per) for a in range(n_attn)
+            for s in range(2) for t in range(ctx0[s], ctx0[s] + K)}
+    for k, old in before.items():
+        new = np.asarray(eng.caches[k])
+        assert new.shape == old.shape
+        changed = np.nonzero((new != old).any(axis=-1))
+        assert set(zip(*map(lambda a: a.tolist(), changed))) == want, k
+
+
 def test_macro_pool_dry_engages_single_step_fallback():
     """ISSUE-3: when the device pool cannot cover a worst-case K-step
     growth, the engine must fall back to single-step mode (whose

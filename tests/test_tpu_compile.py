@@ -14,7 +14,9 @@ The topology is described inside a fixture, never while a module is
 imported: only one process may hold the TPU library, and a test run
 with several workers would otherwise collect different tests in each.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -153,13 +155,43 @@ def test_sharded_map_translate_compiles_on_four_chips(topo, no_cache,
                        out_specs=(P("channel"), P(), P())), st, *lanes)
 
 
-def test_macro_step_fits_one_chip(topo, one_chip, pallas_impl,
-                                  monkeypatch):
-    """One whole K=8 macro step of ServeEngine at llama3.2-1b full width
-    with bf16 weights and a 16 x 4096-token bf16 KV pool: both kernels
-    compiled in, and parameters + pool + temporaries within one chip's
-    HBM. (The KV pool is lane-dense: a [.., KV, D] pool padded to
-    128 lanes plus layout copies needed more than 16 GiB.)"""
+# ops that move a whole pool or a whole layer of it (by opcode, or by the
+# name XLA gives the fusion around one)
+_POOL_MOVES = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][a-z0-9-]*)\(")
+
+
+def _pool_moves(text, pool_shape):
+    """Instructions of the compiled ``text`` that copy or slice a result
+    shaped like the stacked pool, the stack seen as [L, ...] or one
+    layer's pool: (name, opcode, type) each."""
+    n_per, n_attn, *layer = pool_shape
+    dims = [pool_shape, (n_per * n_attn, *layer), layer]
+    shaped = re.compile(r"\[(%s)\]" % "|".join(
+        re.escape(",".join(map(str, d))) for d in dims))
+    found = []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if not m or not shaped.search(m.group(2)):
+            continue
+        name, opcode = m.group(1), m.group(3)
+        if opcode in _POOL_MOVES or any(
+                part in _POOL_MOVES for part in re.split(r"[_.]", name)):
+            found.append((name, opcode, m.group(2)))
+    return found
+
+
+def _macro_step_checks(topo, one_chip, monkeypatch, variant):
+    """Compile one whole K=8 macro step (``variant`` "simple" or "full")
+    of ServeEngine at llama3.2-1b full width with bf16 weights and a
+    16 x 4096-token bf16 KV pool, and check it: both kernels compiled
+    in, and parameters + pool + temporaries within one chip's HBM. (The
+    KV pool is lane-dense: a [.., KV, D] pool padded to 128 lanes plus
+    layout copies needed more than 16 GiB.) The pool rides the layer
+    scan's carry and each token is scattered in place, so the step
+    neither copies nor slices the pool or one layer of it, and its
+    temporaries hold less than one layer's K+V pool (a scan that copied
+    the pool held a whole extra pool)."""
     from repro.configs import get_arch
     from repro.models import Runtime, build_model, transformer
     from repro.parallel.sharding import ParallelCtx
@@ -181,13 +213,37 @@ def test_macro_step_fits_one_chip(topo, one_chip, pallas_impl,
     S = _sds(one_chip)
     shapes = lambda tree: jax.tree.map(lambda x: S(x.shape, x.dtype), tree)
     K, i32 = eng.macro_k, jnp.int32
-    sched = (S((K, N_SLOTS), bool), S((K,), bool), S((K, N_SLOTS), i32))
-    compiled = eng._macro_simple.lower(
+    if variant == "simple":
+        fn = eng._macro_simple
+        sched = (S((K, N_SLOTS), bool), S((K,), bool), S((K, N_SLOTS), i32))
+    else:
+        fn, sched = eng._macro, S((N_SLOTS,), i32)
+    compiled = fn.lower(
         shapes(jax.eval_shape(model.init, jax.random.key(0))),
         shapes(eng.kvm.state), shapes(eng.caches), S((N_SLOTS,), i32),
         S((N_SLOTS,), i32), sched, S((N_SLOTS,), bool),
         S((N_SLOTS,), i32), None, None, 128).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 2
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < HBM_BYTES, used
+    pool = eng.caches["pool_k"]
+    assert pool.shape == (16, 1, N_SLOTS * MAX_PAGES + 1, PAGE, KV * D)
+    assert _pool_moves(text, pool.shape) == []
+    layer_kv_bytes = 2 * math.prod(pool.shape[2:]) * pool.dtype.itemsize
+    assert mem.temp_size_in_bytes < layer_kv_bytes, mem.temp_size_in_bytes
+
+
+def test_macro_step_fits_one_chip(topo, one_chip, pallas_impl,
+                                  monkeypatch):
+    """The ``simple`` scan (no lane can finish mid-scan), the steady
+    state of every benchmark cell: see _macro_step_checks."""
+    _macro_step_checks(topo, one_chip, monkeypatch, "simple")
+
+
+def test_full_macro_step_fits_one_chip(topo, one_chip, pallas_impl,
+                                       monkeypatch):
+    """The ``full`` scan (EOS / budget retirement inside the scan): see
+    _macro_step_checks."""
+    _macro_step_checks(topo, one_chip, monkeypatch, "full")
