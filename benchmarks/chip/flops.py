@@ -1,4 +1,5 @@
-"""Operations and bytes that the served model needs, from its sizes.
+"""Operations and bytes that the served dense decoder needs, from its
+sizes (the dense families' counts; another family brings its own).
 
 The arithmetic follows the usual forward count (2 FLOPs per weight per
 token, plus attention over the real context): a decode token at context

@@ -5,6 +5,10 @@ steps, then check what the window served against the plain reference.
 Everything that belongs to one cell is found by name:
   BENCHMARK.json      the cell, its configuration's file, its metrics
   configs/<c>.json    sizes (the model's own config.json keys) + serving
+  families/<t>.py     the model family its ``model_type`` names: sizes,
+                      the program's architecture, seeded weights, the
+                      reference's layers and the FLOP and byte counts
+                      (load_family says what a family module supplies)
   traffic/<m>.json    the mix, read by traffic.py
   cells/<w>.json      the cell's rate and the limits of its comparison
   metrics/<n>.py      read(run) -> number or None, one file per metric
@@ -22,6 +26,7 @@ import importlib.util
 import json
 import os
 import shutil
+import sys
 import tempfile
 import time
 from typing import Dict, List, Optional
@@ -31,8 +36,6 @@ import numpy as np
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(BENCH_DIR))
 
-import dims as dims_mod  # noqa: E402
-import flops  # noqa: E402
 import traffic  # noqa: E402
 
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -49,7 +52,8 @@ class Cell:
     name: str
     chips: int
     config: dict
-    dims: dims_mod.Dims
+    family: object               # the module families/<model_type>.py
+    dims: object                 # family.dims(config)
     serving: dict
     mix: dict
     params: dict                 # cells/<name>.json
@@ -75,13 +79,55 @@ def load_cell(name: str, root: str = ROOT,
         config = json.load(f)
     with open(os.path.join(bench_dir, "cells", f"{name}.json")) as f:
         params = json.load(f)
+    family = load_family(config.get("model_type"), bench_dir)
     return Cell(
-        name=name, chips=int(wl["chips"]), config=config,
-        dims=dims_mod.dims(config), serving=config["serving"],
+        name=name, chips=int(wl["chips"]), config=config, family=family,
+        dims=family.dims(config), serving=config["serving"],
         mix=traffic.load(bench_dir, wl["traffic"]), params=params,
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, name)],
         bench_dir=bench_dir)
+
+
+def load_family(model_type: str, bench_dir: str = BENCH_DIR):
+    """The module ``families/<model_type>.py``. It supplies
+
+      dims(config)            the family's sizes, read from the published
+                              config.json keys; the harness and traffic.py
+                              read ``n_layers``, ``d_model``, ``vocab`` and
+                              ``max_ctx`` of them
+      arch(d, name)           the program's ArchConfig (on one chip:
+                              build_engine builds no mesh)
+      layer_kinds(d)          the kind of each layer
+      make_layer(key, i, d, kind)   layer i's weights (bf16) from the
+                              seed's key (weights.root_key)
+      make_outer(key, d)      the weights outside the layers: "embed",
+                              "final_norm", "head"
+      to_program(w, d)        the served weights, which weights.make
+                              builds from those two in one jitted call
+                              ({"layers": {kind: [layers of it, ...]},
+                              **outer}), as the program's parameter tree
+      forward_layer(x, w, pos, d, kind, fp8, q_block)   one layer of that
+                              kind over the sequence x [S, d_model], plain
+                              float32, fp8-rounded operands with ``fp8``
+      embed(outer, tokens, d), head(x, outer, fp8, d)
+                              the reference's embedding and logits
+      prefill_flops(d, p), decode_run_flops(d, first_keys, n),
+      paged_attn_run(d, first_keys, n)   what Driver._book counts
+
+    The dense families take all but ``dims`` from dense.py."""
+    where = os.path.join(bench_dir, "families")
+    found = sorted(f[:-3] for f in os.listdir(where) if f.endswith(".py"))
+    if model_type not in found:
+        raise BenchError(f"model_type {model_type!r} has no family module "
+                         f"in {where}; found: {found}")
+    path = os.path.join(where, f"{model_type}.py")
+    spec = importlib.util.spec_from_file_location(
+        f"family_{model_type}", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod        # dataclasses look their module up
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reader(name: str, bench_dir: str = BENCH_DIR):
@@ -177,29 +223,29 @@ def build_engine(cell: Cell, seed: int):
     import jax
     import jax.numpy as jnp
 
-    import weights
-    from repro.configs.base import ArchConfig
     from repro.models import Runtime, build_model
     from repro.serving.config import ServeConfig
     from repro.serving.engine import ServeEngine
 
-    d, s = cell.dims, cell.serving
-    arch = ArchConfig(
-        name=cell.config.get("name", cell.name), family="dense",
-        n_layers=d.n_layers, d_model=d.d_model, n_heads=d.n_heads,
-        n_kv_heads=d.n_kv_heads, head_dim=d.head_dim, d_ff=d.d_ff,
-        vocab_size=d.vocab, qkv_bias=d.qkv_bias, rope_theta=d.rope_theta,
-        norm_eps=d.norm_eps)
+    import weights
+
+    if cell.chips != 1:
+        raise BenchError(f"{cell.name} asks for {cell.chips} chips; "
+                         "build_engine places the model and the map on "
+                         "one chip only")
+    fam, d, s = cell.family, cell.dims, cell.serving
+    arch = fam.arch(d, cell.config.get("name", cell.name))
     rt = Runtime(compute_dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
                  page_size=int(s["page_size"]))
     model = build_model(arch, rt)
-    params = weights.to_program(weights.make(seed, d), d)
+    params = fam.to_program(weights.make(fam, seed, d), d)
     want = model.param_shapes()
     if jax.tree.structure(want) != jax.tree.structure(params) or any(
             (a.shape, a.dtype) != (b.shape, b.dtype) for a, b in
             zip(jax.tree.leaves(want), jax.tree.leaves(params))):
         raise BenchError("the program's parameter tree no longer matches "
-                         "weights.to_program")
+                         f"the {cell.config['model_type']} family's "
+                         "to_program")
     eng = ServeEngine(model, params, config=ServeConfig(
         n_slots=int(s["n_slots"]), max_ctx=d.max_ctx,
         macro_k=int(s["macro_k"]), channels=1))
@@ -333,7 +379,7 @@ class Driver:
         self.eng = eng
         self.run = run
         self.done: Dict[int, List[int]] = {}
-        self.d = run.cell.dims
+        self.fam, self.d = run.cell.family, run.cell.dims
 
     def submit(self, req: traffic.Req, arrival_abs=None) -> int:
         rid = self.eng.submit(req.prompt.tolist(), max_new=req.max_new)
@@ -354,7 +400,7 @@ class Driver:
         return t0, t1
 
     def _book(self, t0: float, t1: float, in_window: bool):
-        run, d = self.run, self.d
+        run, fam, d = self.run, self.fam, self.d
         visible = {rid: (r.out, False) for rid, r in self.eng.active.items()}
         for rid, out in self.done.items():
             log = run.requests.get(rid)
@@ -379,11 +425,11 @@ class Driver:
                 run.tokens_out += b - a
                 if a == 0:
                     run.prompt_tokens += log.prompt_len
-                    run.model_flops += flops.prefill_flops(d, log.prompt_len)
+                    run.model_flops += fam.prefill_flops(d, log.prompt_len)
                 lo_ = max(a, 1)
-                run.model_flops += flops.decode_run_flops(
+                run.model_flops += fam.decode_run_flops(
                     d, log.prompt_len + lo_, b - lo_)
-                f, by = flops.paged_attn_run(d, log.prompt_len + lo_, b - lo_)
+                f, by = fam.paged_attn_run(d, log.prompt_len + lo_, b - lo_)
                 run.pa_flops += f
                 run.pa_bytes += by
                 if log.last is not None:
@@ -610,7 +656,7 @@ def _compare(run: Run, sample, seed, control: bool, log):
     if not sample:
         log("check: the window finished no request to compare")
         return {"max_logit_gap": {"value": float("inf"), "limit": lim}}, None
-    ref = reference.Reference(run.cell.dims, seed)
+    ref = reference.Reference(run.cell.family, run.cell.dims, seed)
     served, ctrl, n_tok = 0.0, 0.0, 0
     t0 = time.perf_counter()
     for rid in sample:

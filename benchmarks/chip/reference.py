@@ -1,9 +1,17 @@
-"""Plain reference forward of the dense decoders the benchmark serves
-(GLM-4 and Mistral as configured): RMSNorm, GQA attention with RoPE and
-optional qkv bias, SwiGLU MLP, untied head. float32 throughout, every
-matmul at HIGHEST precision, no kernels, no cache, no batching. It
-imports nothing of the program and regenerates its weights from the
-seed one layer at a time (weights.py), so it fits beside nothing.
+"""The shared runner of the plain reference forward, and the dense
+decoder layer (GLM-4 and Mistral as configured): RMSNorm, GQA attention
+with RoPE and optional qkv bias, SwiGLU MLP, untied head. float32
+throughout, every matmul at HIGHEST precision, no kernels, no cache, no
+batching. It imports nothing of the program and regenerates its weights
+from the seed one layer at a time, so it fits beside nothing.
+
+``Reference`` takes from the configuration's family module
+(``families/<model_type>.py``, harness.load_family) the kind of each
+layer, that layer's weights and the forward of its kind, the outer
+weights, the embedding and the head; it owns the padding, the chunked
+head, the gaps and the fp8 control. The dense families point at the
+layer below (dense.py); ``attention`` and ``swiglu`` are its halves, for
+families whose layers share them.
 
 Departures from the published models, kept because the served program
 makes them too and the reference follows the configuration as run:
@@ -54,24 +62,38 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
 
 
-def _layer_fwd(x, w, pos, d: Dims, fp8: bool, q_block: int):
-    """One decoder layer over the whole sequence x [S, d_model]."""
+def f32_weights(w, fp8: bool, cols=(), heads=()):
+    """w in float32; with ``fp8`` the weights named in ``cols`` rounded
+    through fp8 per output channel (scale over the input axis 0), those
+    in ``heads`` over their (head, head_dim) input axes."""
     w = jax.tree.map(lambda a: a.astype(jnp.float32), w)
     if fp8:
-        w = {k: (_q8(v, 0) if k in ("wq", "wk", "wv", "wg", "wu", "wd")
-                 else _q8(v, (0, 1)) if k == "wo" else v)
+        w = {k: (_q8(v, 0) if k in cols else _q8(v, (0, 1)) if k in heads
+                 else v)
              for k, v in w.items()}
-    act = (lambda a: _q8(a, -1)) if fp8 else (lambda a: a)
+    return w
+
+
+def row_act(fp8: bool):
+    """The rounding of an activation that enters a matmul: per row
+    through fp8 in the control, none otherwise."""
+    return (lambda a: _q8(a, -1)) if fp8 else (lambda a: a)
+
+
+def attention(x, w, pos, d, act, q_block: int, rope: bool = True):
+    """x plus the pre-norm (``ln1``) GQA attention sublayer over the
+    whole sequence x [S, d_model], causal, with RoPE unless ``rope`` is
+    False (NoPE)."""
     h = act(_rms(x, w["ln1"], d.norm_eps))
     q = jnp.einsum("sd,dhk->shk", h, w["wq"], precision=HI)
     k = jnp.einsum("sd,dhk->shk", h, w["wk"], precision=HI)
     v = jnp.einsum("sd,dhk->shk", h, w["wv"], precision=HI)
     if d.qkv_bias:
         q, k, v = q + w["bq"], k + w["bk"], v + w["bv"]
-    q = _rope(q, pos, d.rope_theta)
-    k = _rope(k, pos, d.rope_theta)
-    if fp8:
-        q, k, v = act(q), act(k), act(v)
+    if rope:
+        q = _rope(q, pos, d.rope_theta)
+        k = _rope(k, pos, d.rope_theta)
+    q, k, v = act(q), act(k), act(v)
     S, g = x.shape[0], d.n_heads // d.n_kv_heads
     qg = q.reshape(S, d.n_kv_heads, g, d.head_dim) / np.sqrt(d.head_dim)
 
@@ -85,11 +107,23 @@ def _layer_fwd(x, w, pos, d: Dims, fp8: bool, q_block: int):
 
     o = jax.lax.map(block, jnp.arange(S // q_block))
     o = act(o.reshape(S, d.n_heads, d.head_dim))
-    x = x + jnp.einsum("shk,hkd->sd", o, w["wo"], precision=HI)
+    return x + jnp.einsum("shk,hkd->sd", o, w["wo"], precision=HI)
+
+
+def swiglu(x, w, d, act):
+    """x plus the pre-norm (``ln2``) SwiGLU MLP sublayer."""
     h = act(_rms(x, w["ln2"], d.norm_eps))
     a = jax.nn.silu(jnp.dot(h, w["wg"], precision=HI)) \
         * jnp.dot(h, w["wu"], precision=HI)
     return x + jnp.dot(act(a), w["wd"], precision=HI)
+
+
+def _layer_fwd(x, w, pos, d: Dims, fp8: bool, q_block: int):
+    """One dense decoder layer over the whole sequence x [S, d_model]."""
+    w = f32_weights(w, fp8, cols=("wq", "wk", "wv", "wg", "wu", "wd"),
+                    heads=("wo",))
+    act = row_act(fp8)
+    return swiglu(attention(x, w, pos, d, act, q_block), w, d, act)
 
 
 class Reference:
@@ -99,19 +133,23 @@ class Reference:
     head runs over ``chunk`` positions at a time, so a run compiles a few
     fixed shapes that the persistent compile cache keeps."""
 
-    def __init__(self, d: Dims, seed: int, *, q_block: int = 256,
+    def __init__(self, fam, d, seed: int, *, q_block: int = 256,
                  min_len: int = 1024, chunk: int = 512):
-        self.d = d
         self.key = W.root_key(seed)
         self.min_len, self.chunk = min_len, chunk
-        self._layer_w = W.layer_fn(d)
-        self.outer = W.outer_fn(d)(self.key)
-        self._embed = jax.jit(lambda e, t: e[t].astype(jnp.float32))
-        self._fwd = {m: jax.jit(functools.partial(
-            _layer_fwd, d=d, fp8=(m == "fp8"), q_block=q_block))
-            for m in ("f32", "fp8")}
-        self._gaps = jax.jit(functools.partial(_chunk_gaps, d=d),
-                             static_argnums=(5,))
+        self.kinds = fam.layer_kinds(d)
+        self._layer_w = {k: jax.jit(functools.partial(
+            fam.make_layer, d=d, kind=k)) for k in set(self.kinds)}
+        self.outer = jax.jit(functools.partial(fam.make_outer, d=d))(
+            self.key)
+        self._embed = jax.jit(functools.partial(fam.embed, d=d))
+        self._fwd = {(k, m): jax.jit(functools.partial(
+            fam.forward_layer, d=d, kind=k, fp8=(m == "fp8"),
+            q_block=q_block))
+            for k in set(self.kinds) for m in ("f32", "fp8")}
+        self._gaps = jax.jit(functools.partial(
+            _chunk_gaps, head=functools.partial(fam.head, d=d)),
+            static_argnums=(5,))
 
     def hidden(self, tokens, mode: str = "f32"):
         """Final-layer activations [S_pad, d_model] of tokens [S]."""
@@ -119,10 +157,11 @@ class Reference:
         n = max(self.min_len, 1 << (S - 1).bit_length())
         toks = np.zeros(n, np.int32)
         toks[:S] = tokens                 # causal: pad keys never reach
-        x = self._embed(self.outer["embed"], jnp.asarray(toks))
+        x = self._embed(self.outer, jnp.asarray(toks))
         pos = jnp.arange(n)
-        for i in range(self.d.n_layers):
-            x = self._fwd[mode](x, self._layer_w(self.key, i), pos)
+        for i, kind in enumerate(self.kinds):
+            x = self._fwd[kind, mode](x, self._layer_w[kind](self.key, i),
+                                      pos)
         return x
 
     def gaps(self, prompt, served, control: bool = False):
@@ -152,15 +191,21 @@ class Reference:
         return res
 
 
-def _head(x, outer, fp8, d: Dims):
+def embed(outer, tokens, d: Dims):
+    """The dense families' embedding rows, in float32."""
+    return outer["embed"][tokens].astype(jnp.float32)
+
+
+def head(x, outer, fp8, d: Dims):
+    """The dense families' final RMSNorm and untied head."""
     h = _rms(x, outer["final_norm"].astype(jnp.float32), d.norm_eps)
-    head = outer["head"].astype(jnp.float32)
+    w = outer["head"].astype(jnp.float32)
     if fp8:
-        h, head = _q8(h, -1), _q8(head, 0)
-    return jnp.dot(h, head, precision=HI)
+        h, w = _q8(h, -1), _q8(w, 0)
+    return jnp.dot(h, w, precision=HI)
 
 
-def _chunk_gaps(hf, hc, outer, served, lo, control, d: Dims):
+def _chunk_gaps(hf, hc, outer, served, lo, control, head):
     """Gaps at positions [lo, lo + len(served)): the served tokens', and
     (control) those of the fp8 forward's first choices, both measured in
     reference logits. Rows past the activations read zero padding."""
@@ -171,11 +216,11 @@ def _chunk_gaps(hf, hc, outer, served, lo, control, d: Dims):
         return jax.lax.dynamic_slice_in_dim(h, lo, n, 0)
 
     hf, hc = rows_of(hf), rows_of(hc)
-    ref = _head(hf, outer, False, d)
+    ref = head(hf, outer, False)
     best = ref.max(-1)
     rows = jnp.arange(ref.shape[0])
     g = best - ref[rows, served]
     if not control:
         return g, g
-    t = _head(hc, outer, True, d).argmax(-1)
+    t = head(hc, outer, True).argmax(-1)
     return g, best - ref[rows, t]

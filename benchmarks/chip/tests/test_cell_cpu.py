@@ -7,7 +7,9 @@ benchmark, with no edit to any file the benchmark has. The runs check
 that the harness finds them by name, that the served tokens pass the
 comparison with the plain reference, that the fp8 control fails it, and
 that a token altered where the engine produces it makes the run
-incorrect."""
+incorrect. ``tiny_hybrid`` brings a model family of its own as a new
+file (tests/tiny/families/tiny_hybrid.py): Mamba-2 and NoPE attention
+layers, whose paged attention runs in half of the layers."""
 import json
 import os
 import shutil
@@ -17,7 +19,9 @@ import time
 
 import pytest
 
+import flops
 import harness
+from dims import Dims
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
@@ -34,18 +38,22 @@ def tiny_root(tmp_path_factory):
     shutil.copytree(os.path.join(HERE, "tiny"), bench, dirs_exist_ok=True)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         b = json.load(f)
-    b["configs"].append({"name": "tiny", "source": "test",
-                         "file": "benchmarks/chip/configs/tiny.json",
-                         "reduced": [], "why": "test"})
-    for cell, mix in (("tiny.backlog", "tiny_backlog"),
-                      ("tiny.chat", "tiny_chat")):
-        b["workloads"].append({"name": cell, "config": "tiny",
+    for config in ("tiny", "tiny_hybrid"):
+        b["configs"].append({"name": config, "source": "test",
+                             "file": f"benchmarks/chip/configs/{config}.json",
+                             "reduced": [], "why": "test"})
+    cells = (("tiny.backlog", "tiny", "tiny_backlog", "decode_long"),
+             ("tiny.chat", "tiny", "tiny_chat", "chat_open"),
+             ("tiny_hybrid.backlog", "tiny_hybrid", "tiny_backlog",
+              "decode_long"))
+    for cell, config, mix, _ in cells:
+        b["workloads"].append({"name": cell, "config": config,
                                "traffic": mix, "chips": 1, "why": "test"})
     for m in b["end_to_end"]:       # the tiny cells join their kind's
         if "workloads" in m:
-            m["workloads"] += [tiny for tiny, kind in (
-                ("tiny.backlog", "decode_long"), ("tiny.chat", "chat_open"))
-                if any(w.endswith(kind) for w in m["workloads"])]
+            m["workloads"] += [tiny for tiny, _, _, kind in cells
+                               if any(w.endswith(kind)
+                                      for w in m["workloads"])]
     with open(root / "BENCHMARK.json", "w") as f:
         json.dump(b, f)
     return root
@@ -126,3 +134,69 @@ def test_no_tpu_no_result():
         timeout=300)
     assert p.returncode != 0
     assert "{" not in p.stdout
+
+
+@pytest.fixture(scope="module")
+def hybrid(tiny_root):
+    """One run of the hybrid cell, with the run the harness observed and
+    the (first keys, tokens) of every paged-attention count it booked."""
+    bench = tiny_root / "benchmarks" / "chip"
+    cell = harness.load_cell("tiny_hybrid.backlog", root=str(tiny_root),
+                             bench_dir=str(bench))
+    booked, runs, lines = [], [], []
+    count, compare = cell.family.paged_attn_run, harness._compare
+
+    def counted(d, first, n):
+        booked.append((first, n))
+        return count(d, first, n)
+
+    def kept(run, *a):
+        runs.append(run)
+        return compare(run, *a)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(cell.family, "paged_attn_run", counted)
+        mp.setattr(harness, "_compare", kept)
+        out = harness.run_cell(
+            cell, 2**33 + 29, SECONDS, trace=False,
+            t_start=time.perf_counter(), require_tpu=False,
+            log=lambda *a: lines.append(" ".join(map(str, a))))
+    return cell, out, runs[0], booked, lines
+
+
+def test_hybrid_cell_found_and_correct(hybrid):
+    cell, out, _, _, lines = hybrid
+    assert cell.family.__name__ == "family_tiny_hybrid"
+    assert cell.family.layer_kinds(cell.dims) == ["mamba", "attn"] * 2
+    assert out["correct"], lines
+    assert out["failed"] == 0 and out["attempted"] > 0
+    assert any("compiles in window: 0" in ln for ln in lines)
+
+
+def test_hybrid_paged_attention_counts_attention_layers(hybrid):
+    cell, _, run, booked, _ = hybrid
+    d = cell.dims
+    assert booked and run.pa_bytes > 0
+
+    def one_layer(first, n):     # live K and V of each key, q and out
+        return sum(2 * (first + i) * d.n_kv_heads * d.head_dim * 2
+                   + 2 * d.n_heads * d.head_dim * 2 for i in range(n))
+
+    assert run.pa_bytes == 2 * sum(one_layer(f, n) for f, n in booked)
+    # the dense count takes every one of the four layers
+    dense = Dims(n_layers=d.n_layers, d_model=d.d_model, n_heads=d.n_heads,
+                 n_kv_heads=d.n_kv_heads, head_dim=d.head_dim, d_ff=d.d_ff,
+                 vocab=d.vocab, qkv_bias=False, rope_theta=1e4,
+                 norm_eps=d.norm_eps, max_ctx=d.max_ctx)
+    assert sum(flops.paged_attn_run(dense, f, n)[1] for f, n in booked) \
+        == 2 * run.pa_bytes
+
+
+def test_hybrid_fp8_control_is_rejected(tiny_root):
+    lines = []
+    out = _run(tiny_root, "tiny_hybrid.backlog", 2**33 + 29, lines,
+               control=True)
+    assert not out["correct"], lines
+    gap = out["checks"]["max_logit_gap"]
+    assert gap["value"] > gap["limit"]
+    assert out["control"]["served_max_logit_gap"] <= gap["limit"]

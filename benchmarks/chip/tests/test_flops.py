@@ -1,23 +1,26 @@
 """FLOP and byte counts against hand-computed values for both served
-configurations."""
+configurations, reached through their family modules (the counts that
+Driver._book adds) and flops.py (the dense terms they sum)."""
 import json
 import os
 
 import pytest
 
-import dims
 import flops
+import harness
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _dims(name):
+def _family(name):
     with open(os.path.join(BENCH, "configs", f"{name}.json")) as f:
-        return dims.dims(json.load(f))
+        config = json.load(f)
+    fam = harness.load_family(config["model_type"])
+    return fam, fam.dims(config)
 
 
 def test_glm_sizes_and_counts():
-    d = _dims("glm4-9b")
+    _, d = _family("glm4-9b")
     assert (d.n_layers, d.d_model, d.n_heads, d.n_kv_heads, d.head_dim,
             d.d_ff, d.vocab, d.qkv_bias) == (20, 4096, 32, 2, 128, 13696,
                                              151552, True)
@@ -38,7 +41,7 @@ def test_glm_sizes_and_counts():
 
 
 def test_mistral_sizes_and_counts():
-    d = _dims("mistral-7b")
+    fam, d = _family("mistral-7b")
     assert (d.n_layers, d.d_model, d.n_kv_heads, d.d_ff, d.vocab,
             d.qkv_bias, d.rope_theta) == (8, 4096, 8, 14336, 32000, False,
                                           1e6)
@@ -47,20 +50,20 @@ def test_mistral_sizes_and_counts():
     assert flops.matmul_flops_per_token(d) == 8 * per_layer
     # prefill of 4 tokens: 4 tokens of matmuls, causal attention over
     # 1+2+3+4 = 10 keys, one head row
-    assert flops.prefill_flops(d, 4) == (4 * 8 * per_layer
-                                         + 8 * 4 * 32 * 128 * 10
-                                         + 2 * 4096 * 32000)
+    assert fam.prefill_flops(d, 4) == (4 * 8 * per_layer
+                                       + 8 * 4 * 32 * 128 * 10
+                                       + 2 * 4096 * 32000)
     assert flops.paged_attn_bytes(d, 2048) == 8 * (2 * 2048 * 1024 * 2
                                                    + 2 * 4096 * 2)
 
 
 @pytest.mark.parametrize("name", ["glm4-9b", "mistral-7b"])
 def test_runs_are_sums_of_tokens(name):
-    d = _dims(name)
+    fam, d = _family(name)
     first, n = 777, 19
-    assert flops.decode_run_flops(d, first, n) == sum(
+    assert fam.decode_run_flops(d, first, n) == sum(
         flops.decode_flops(d, first + i) for i in range(n))
-    f, b = flops.paged_attn_run(d, first, n)
+    f, b = fam.paged_attn_run(d, first, n)
     assert b == sum(flops.paged_attn_bytes(d, first + i) for i in range(n))
     assert f == sum(flops.attn_flops(d, first + i) for i in range(n))
-    assert flops.decode_run_flops(d, first, 0) == 0
+    assert fam.decode_run_flops(d, first, 0) == 0

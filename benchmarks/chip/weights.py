@@ -3,17 +3,21 @@ served in (bf16), and the map from them onto the program's parameter
 tree.
 
 Each decoder layer's weights are a pure function of (seed, layer): the
-set-up makes all layers in one jitted call (``make``), and the plain
-reference makes one layer at a time (``make_layer``) after the program
-is gone, so it takes nothing the program holds. Projections have
+set-up makes all layers of any family in one jitted call (``make``, from
+the family's ``make_layer`` and ``make_outer``), and the plain reference
+makes one layer at a time from the same functions after the program is
+gone, so it takes nothing the program holds. Projections have
 std 1/sqrt(fan_in); the embedding std 0.02; RMSNorm weights are stored
 as offsets from 1 (``gamma = 1 + w``, std 0.1) and the qkv biases have
 std 0.02, so that every parameter moves the result.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from dims import Dims
 
@@ -63,31 +67,37 @@ def _outer(seed_key, d: Dims):
             "head": _normal(ks[2], (d.d_model, d.vocab), d.d_model ** -0.5)}
 
 
-def make(seed: int, d: Dims):
-    """All weights in one jitted call: {"layers": leaves [L, ...],
-    "embed", "final_norm", "head"}."""
+def make(fam, seed: int, d):
+    """Every weight of the family ``fam`` (harness.load_family) in one
+    jitted call: {"layers": {kind: leaves [layers of that kind, ...]}}
+    plus what ``fam.make_outer`` gives ("embed", "final_norm", "head")."""
+    kinds = fam.layer_kinds(d)
+    rows = {k: np.asarray([i for i, x in enumerate(kinds) if x == k],
+                          np.int32) for k in dict.fromkeys(kinds)}
 
     def build(key):
-        layers = jax.lax.map(lambda i: _layer(_layer_key(key, i), d),
-                             jnp.arange(d.n_layers))
-        return {"layers": layers, **_outer(key, d)}
+        layers = {k: jax.lax.map(
+            functools.partial(fam.make_layer, key, d=d, kind=k), ix)
+            for k, ix in rows.items()}
+        return {"layers": layers, **fam.make_outer(key, d)}
 
     return jax.jit(build)(root_key(seed))
 
 
-def layer_fn(d: Dims):
-    """jitted (seed key, i) -> layer i's weights, identical to make()'s."""
-    return jax.jit(lambda key, i: _layer(_layer_key(key, i), d))
+def make_layer(key, i, d: Dims):
+    """(seed key, i) -> the dense layer i's weights."""
+    return _layer(_layer_key(key, i), d)
 
 
-def outer_fn(d: Dims):
-    return jax.jit(lambda key: _outer(key, d))
+def make_outer(key, d: Dims):
+    """(seed key) -> the embedding, final norm and head."""
+    return _outer(key, d)
 
 
 def to_program(w, d: Dims):
     """The program's parameter tree (repro.models: one scanned period of
     one layer kind, leaves stacked [n_layers, ...])."""
-    L = w["layers"]
+    L = w["layers"]["dense"]
     mixer = {k: L[k] for k in ("wq", "wk", "wv", "wo")}
     if d.qkv_bias:
         mixer.update(bq=L["bq"], bk=L["bk"], bv=L["bv"])
