@@ -23,6 +23,16 @@ class MoEConfig:
     dense_residual: bool = False  # arctic: dense FFN running in parallel with MoE
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # the chip's share of an expert-parallel deployment: the router
+    # keeps all n_experts outputs, and this layer holds and computes
+    # experts held_offset .. held_offset + held - 1 (n_held 0 = all)
+    n_held: int = 0
+    held_offset: int = 0
+    shared_d_ff: int = 0         # granite: one shared SwiGLU expert
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,6 +73,14 @@ class ArchConfig:
     norm_eps: float = 1e-5
     post_norms: bool = False     # gemma2 post-attention/post-ffn extra norms
     act: str = "silu"            # 'silu' (SwiGLU) | 'gelu' (GeGLU)
+    # granite scalar multipliers: x = embed * embedding_multiplier; each
+    # residual branch is scaled by residual_multiplier; logits are
+    # divided by logits_scaling; attention scores are scaled by
+    # attention_multiplier (0 = the usual 1/sqrt(head_dim))
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: float = 0.0
     # --- moe ---
     moe: Optional[MoEConfig] = None
     # --- ssm / hybrid ---

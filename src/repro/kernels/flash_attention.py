@@ -81,9 +81,10 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
-                    segment_ids=None, bidirectional=False,
+                    segment_ids=None, bidirectional=False, scale=None,
                     q_block=512, kv_block=512, interpret=False):
-    """q [B,Sq,H,D]; k,v [B,Skv,KV,D] -> [B,Sq,H,D]."""
+    """q [B,Sq,H,D]; k,v [B,Skv,KV,D] -> [B,Sq,H,D]; ``scale``
+    multiplies q.k (None: 1/sqrt(D))."""
     if segment_ids is not None:
         raise NotImplementedError(
             "segment_ids: use the blocked-jnp lowering (impl='blocked')")
@@ -105,7 +106,8 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
     vt = v.transpose(0, 2, 1, 3)
     grid = (b, h, sq_p // q_block, skv_p // kv_block)
     kernel = functools.partial(
-        _fa_kernel, scale=1.0 / math.sqrt(d), causal=causal, window=window,
+        _fa_kernel, scale=1.0 / math.sqrt(d) if scale is None else scale,
+        causal=causal, window=window,
         softcap=softcap, q_block=q_block, kv_block=kv_block, seq_kv=skv,
         bidirectional=bidirectional, q_offset=skv - sq)
     out = pl.pallas_call(

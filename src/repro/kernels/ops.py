@@ -37,32 +37,35 @@ def _default_impl(impl: Optional[str]) -> str:
 
 # ----------------------------------------------------------------------
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
-                    segment_ids=None, bidirectional=False, impl=None,
-                    q_chunk=512, kv_chunk=512):
+                    segment_ids=None, bidirectional=False, scale=None,
+                    impl=None, q_chunk=512, kv_chunk=512):
+    """``scale`` multiplies q.k (None: 1/sqrt(head_dim))."""
     sel = _default_impl(impl)
     if sel in ("pallas", "pallas_interpret"):
         from repro.kernels import flash_attention as fa   # raises on segments
         return fa.flash_attention(
             q, k, v, causal=causal, window=window, softcap=softcap,
             segment_ids=segment_ids, bidirectional=bidirectional,
-            interpret=(sel == "pallas_interpret"))
+            scale=scale, interpret=(sel == "pallas_interpret"))
     if sel == "blocked":
         return ref.flash_attention_blocked(
             q, k, v, causal=causal, window=window, softcap=softcap,
             segment_ids=segment_ids, bidirectional=bidirectional,
-            q_chunk=q_chunk, kv_chunk=kv_chunk)
+            scale=scale, q_chunk=q_chunk, kv_chunk=kv_chunk)
     return ref.attention_naive(q, k, v, causal=causal, window=window,
                                softcap=softcap, segment_ids=segment_ids,
-                               bidirectional=bidirectional)
+                               bidirectional=bidirectional, scale=scale)
 
 
 # ----------------------------------------------------------------------
 def paged_attention(q, k_pool, v_pool, block_table, ctx_lens, *,
                     layer=None, softcap=0.0, window=0, page_mask=None,
-                    return_stats=False, impl=None, pages_per_chunk=None):
+                    scale=None, return_stats=False, impl=None,
+                    pages_per_chunk=None):
     """q [B,H,D]; lane-dense pools [NB,P,KV*D] (kernels/paged_attention
     .py says why), or the stack's [L,NB,P,KV*D] with ``layer`` the one
-    to read; the jnp lowerings view them as [.., NB,P,KV,D]."""
+    to read; the jnp lowerings view them as [.., NB,P,KV,D]. ``scale``
+    multiplies q.k (None: 1/sqrt(head_dim))."""
     sel = _default_impl(impl)
     if sel in ("pallas", "pallas_interpret") and page_mask is not None:
         raise NotImplementedError(
@@ -72,7 +75,8 @@ def paged_attention(q, k_pool, v_pool, block_table, ctx_lens, *,
         from repro.kernels import paged_attention as pa
         return pa.paged_attention(
             q, k_pool, v_pool, block_table, ctx_lens, layer=layer,
-            softcap=softcap, window=window, return_stats=return_stats,
+            softcap=softcap, window=window, scale=scale,
+            return_stats=return_stats,
             interpret=(sel == "pallas_interpret"))
     *lead, p, kvd = k_pool.shape
     d = q.shape[-1]
@@ -88,12 +92,12 @@ def paged_attention(q, k_pool, v_pool, block_table, ctx_lens, *,
             pages_per_chunk = maxp if maxp * p <= 1024 else 8
         return ref.paged_attention_blocked(
             q, k_pool, v_pool, block_table, ctx_lens, layer=layer,
-            softcap=softcap, window=window, page_mask=page_mask,
+            softcap=softcap, window=window, page_mask=page_mask, scale=scale,
             pages_per_chunk=pages_per_chunk, return_stats=return_stats)
     return ref.paged_attention_naive(q, k_pool, v_pool, block_table,
                                      ctx_lens, layer=layer, softcap=softcap,
                                      window=window, page_mask=page_mask,
-                                     return_stats=return_stats)
+                                     scale=scale, return_stats=return_stats)
 
 
 # ----------------------------------------------------------------------
@@ -142,5 +146,42 @@ def fmmu_translate(tags, valid, refbits, data, backing, dlpns, touch, *,
                                   entries_per_block=entries_per_block)
 
 
+def mamba_decode_step(state, x, dt, A, B, C, D, *, layer=None, impl=None):
+    """One-token Mamba-2 state update. state [Bt,H,P,N] f32, or the
+    stack's [L,Bt,H,P,N] with ``layer`` the one to update (in place);
+    x [Bt,H,P]; dt [Bt,H]; A, D [H]; B, C [Bt,N].
+    Returns (y [Bt,H,P], state)."""
+    sel = _default_impl(impl)
+    if sel in ("pallas", "pallas_interpret"):
+        from repro.kernels import ssm_step
+        return ssm_step.ssm_step(state, x, dt, A, B, C, D, layer=layer,
+                                 interpret=(sel == "pallas_interpret"))
+    return ref.mamba_decode_step(state, x, dt, A, B, C, D, layer=layer)
+
+
+# ----------------------------------------------------------------------
+def grouped_matmul(lhs, rhs, group_sizes, *, impl=None):
+    """Rows of ``lhs`` [M,K] sorted by group times their group's
+    ``rhs`` [G,K,N]: the first group_sizes[0] rows by rhs[0], the next
+    by rhs[1], ...; rows past sum(group_sizes) are unspecified (the
+    Pallas kernel leaves them unwritten). Out [M,N] in lhs's dtype."""
+    sel = _default_impl(impl)
+    if sel in ("pallas", "pallas_interpret"):
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        m, k = lhs.shape
+        n = rhs.shape[2]
+        tm = 128
+        mp = -(-m // tm) * tm
+        tiling = (tm, k if k <= 1024 else 512, n if n <= 1024 else 1024)
+        # positional: gmm is a custom_vjp (lhs, rhs, group_sizes,
+        # preferred_element_type, tiling, group_offset, existing_out,
+        # transpose_rhs, interpret)
+        out = gmm(jnp.pad(lhs, ((0, mp - m), (0, 0))), rhs, group_sizes,
+                  lhs.dtype, tiling, None, None, False,
+                  sel == "pallas_interpret")
+        return out[:m]
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=lhs.dtype)
+
+
 combine_partial_attention = ref.combine_partial_attention
-mamba_decode_step = ref.mamba_decode_step

@@ -94,11 +94,12 @@ def _pa_kernel(table_ref, ctx_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_attention(q, k_pool, v_pool, block_table, ctx_lens, *,
-                    layer=None, softcap=0.0, window=0, return_stats=False,
-                    interpret=False):
+                    layer=None, softcap=0.0, window=0, scale=None,
+                    return_stats=False, interpret=False):
     """q [B,H,D]; pools [NB,P,KV*D], or [L,NB,P,KV*D] with ``layer`` the
-    one to read; block_table [B,MAXP] int32; ctx_lens [B] int32
-    -> [B,H,D] (+ (m,l) [B,H] fp32)."""
+    one to read; block_table [B,MAXP] int32; ctx_lens [B] int32;
+    ``scale`` multiplies q.k (None: 1/sqrt(D)) -> [B,H,D] (+ (m,l)
+    [B,H] fp32)."""
     if layer is None:
         k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
@@ -111,7 +112,8 @@ def paged_attention(q, k_pool, v_pool, block_table, ctx_lens, *,
     q_bd = jnp.where(own[None, :, :, None], q[:, :, None, :],
                      0).reshape(b, h, kvd)
     kernel = functools.partial(
-        _pa_kernel, scale=1.0 / math.sqrt(d), softcap=softcap,
+        _pa_kernel, scale=1.0 / math.sqrt(d) if scale is None else scale,
+        softcap=softcap,
         window=window, page=page)
     per_b = lambda bi, i, tbl, ctx, ly: (bi, 0, 0)
     page_of = lambda bi, i, tbl, ctx, ly: (ly[0], tbl[bi, i], 0, 0)

@@ -43,14 +43,19 @@ def _group_kv(k: jnp.ndarray, n_heads: int) -> jnp.ndarray:
 # ======================================================================
 # Full attention — naive oracle
 # ======================================================================
+def _scale(scale, d: int) -> float:
+    """The q.k multiplier: ``scale``, or 1/sqrt(head_dim) when None."""
+    return 1.0 / math.sqrt(d) if scale is None else scale
+
+
 def attention_naive(q, k, v, *, causal=True, window=0, softcap=0.0,
-                    segment_ids=None, bidirectional=False):
+                    segment_ids=None, bidirectional=False, scale=None):
     """q [B,Sq,H,D]; k,v [B,Skv,KV,D] -> [B,Sq,H,D]. fp32 math."""
     b, sq, h, d = q.shape
     skv = k.shape[1]
     kf = _group_kv(k, h).astype(jnp.float32)
     vf = _group_kv(v, h).astype(jnp.float32)
-    qf = q.astype(jnp.float32) * (1.0 / math.sqrt(d))
+    qf = q.astype(jnp.float32) * _scale(scale, d)
     logits = jnp.einsum("bqhd,bkhd->bhqk", qf, kf)
     logits = _softcap(logits, softcap)
     qpos = jnp.arange(sq)[:, None] + (skv - sq)  # right-aligned query positions
@@ -103,7 +108,7 @@ def _online_block_softcap(carry, qf, kc, vc, mask, softcap):
 
 def flash_attention_blocked(q, k, v, *, causal=True, window=0, softcap=0.0,
                             segment_ids=None, bidirectional=False,
-                            q_chunk=512, kv_chunk=512):
+                            scale=None, q_chunk=512, kv_chunk=512):
     """Triangular-work blocked attention.
 
     Python loop over query chunks gives each chunk a *static* KV extent
@@ -117,10 +122,10 @@ def flash_attention_blocked(q, k, v, *, causal=True, window=0, softcap=0.0,
     if sq % q_chunk or skv % kv_chunk:
         return attention_naive(q, k, v, causal=causal, window=window,
                                softcap=softcap, segment_ids=segment_ids,
-                               bidirectional=bidirectional)
+                               bidirectional=bidirectional, scale=scale)
     kf = _group_kv(k, h).astype(jnp.float32)
     vf = _group_kv(v, h).astype(jnp.float32)
-    qf = q.astype(jnp.float32) * (1.0 / math.sqrt(d))
+    qf = q.astype(jnp.float32) * _scale(scale, d)
     off = skv - sq                                   # right-aligned queries
     seg_q, seg_k = (segment_ids if segment_ids is not None else (None, None))
 
@@ -197,7 +202,7 @@ def _pages(pool, layer, pages):
 
 def paged_attention_naive(q, k_pool, v_pool, block_table, ctx_lens, *,
                           layer=None, softcap=0.0, window=0, page_mask=None,
-                          return_stats=False):
+                          scale=None, return_stats=False):
     """One-token decode attention over a paged KV pool.
 
     q           [B, H, D]
@@ -218,7 +223,7 @@ def paged_attention_naive(q, k_pool, v_pool, block_table, ctx_lens, *,
     vseq = vseq.reshape(b, maxp * p, kv, d)
     kseq = _group_kv(kseq, h)
     vseq = _group_kv(vseq, h)
-    qf = q.astype(jnp.float32) * (1.0 / math.sqrt(d))
+    qf = q.astype(jnp.float32) * _scale(scale, d)
     logits = jnp.einsum("bhd,bkhd->bhk", qf, kseq)
     logits = _softcap(logits, softcap)
     pos = jnp.arange(maxp * p)[None, :]
@@ -239,7 +244,8 @@ def paged_attention_naive(q, k_pool, v_pool, block_table, ctx_lens, *,
 
 def paged_attention_blocked(q, k_pool, v_pool, block_table, ctx_lens, *,
                             layer=None, softcap=0.0, window=0, page_mask=None,
-                            pages_per_chunk=8, return_stats=False):
+                            scale=None, pages_per_chunk=8,
+                            return_stats=False):
     """Flash-decoding style: scan over page chunks with online softmax.
     Live memory O(pages_per_chunk * P) per (B,H). Pools as for
     paged_attention_naive."""
@@ -250,7 +256,7 @@ def paged_attention_blocked(q, k_pool, v_pool, block_table, ctx_lens, *,
     if maxp % c:
         c = 1
     n_chunks = maxp // c
-    qf = q.astype(jnp.float32) * (1.0 / math.sqrt(d))
+    qf = q.astype(jnp.float32) * _scale(scale, d)
     group = h // kv
 
     def per_b(qb, table_b, ctx_b, pmask_b):
@@ -427,15 +433,19 @@ def mamba_chunk_scan_blocked(x, dt, A, B, C, D, *, chunk,
     return y.astype(x.dtype), final
 
 
-def mamba_decode_step(state, x, dt, A, B, C, D):
-    """Single-token SSD recurrence. state [Bt,H,P,N]; x [Bt,H,P];
+def mamba_decode_step(state, x, dt, A, B, C, D, *, layer=None):
+    """Single-token SSD recurrence. state [Bt,H,P,N], or the stack's
+    [L,Bt,H,P,N] with ``layer`` the one to update; x [Bt,H,P];
     dt [Bt,H]; B,C [Bt,N]. Returns (y [Bt,H,P], new_state)."""
+    stack, state = (None, state) if layer is None else (state, state[layer])
     da = jnp.exp(dt.astype(jnp.float32) * A.astype(jnp.float32)[None, :])
     xf = x.astype(jnp.float32)
     state = state * da[..., None, None] + jnp.einsum(
         "bh,bhp,bn->bhpn", dt.astype(jnp.float32), xf, B.astype(jnp.float32))
     y = jnp.einsum("bhpn,bn->bhp", state, C.astype(jnp.float32))
     y = y + xf * D.astype(jnp.float32)[None, :, None]
+    if stack is not None:
+        state = stack.at[layer].set(state)
     return y.astype(x.dtype), state
 
 

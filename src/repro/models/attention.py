@@ -49,6 +49,12 @@ def attention_specs(cfg, *, cross: bool = False):
     return specs
 
 
+def _scale(cfg):
+    """The q.k multiplier the kernels take: granite's
+    attention_multiplier, or None for their 1/sqrt(head_dim)."""
+    return cfg.attention_multiplier or None
+
+
 def _masked_impl(rt: Runtime) -> str:
     """Kernel lowering for calls that carry segment or page masks: the
     Pallas kernels take none (ops raises), so these name the blocked
@@ -84,7 +90,7 @@ def attn_forward(params, x, cfg, rt: Runtime, *, positions, kind="global",
     out = ops.flash_attention(
         q, k, v, causal=not bidirectional, window=window,
         softcap=cfg.attn_softcap, segment_ids=segs,
-        bidirectional=bidirectional,
+        bidirectional=bidirectional, scale=_scale(cfg),
         impl=rt.kernel_impl if segs is None else _masked_impl(rt),
         q_chunk=rt.q_chunk, kv_chunk=rt.kv_chunk)
     y = jnp.einsum("bshk,hkd->bsd", out, common.cast(params["wo"], rt.compute_dtype))
@@ -104,7 +110,7 @@ def cross_forward(params, x, kv_cache, cfg, rt: Runtime, *, src_valid=None):
         seg_q = jnp.ones(q.shape[:2], jnp.int32)
         segs = (seg_q, src_valid.astype(jnp.int32))
     out = ops.flash_attention(q, k, v, causal=False, bidirectional=True,
-                              segment_ids=segs,
+                              segment_ids=segs, scale=_scale(cfg),
                               impl=(rt.kernel_impl if segs is None
                                     else _masked_impl(rt)),
                               q_chunk=rt.q_chunk, kv_chunk=rt.kv_chunk)
@@ -157,7 +163,7 @@ def attn_decode_paged(params, x, cfg, rt: Runtime, *, pool_k, pool_v,
     window = cfg.sliding_window if kind == "local" else 0
     res = ops.paged_attention(
         q[:, 0], pool_k, pool_v, block_table, ctx_lens + 1, layer=layer,
-        softcap=cfg.attn_softcap, window=window,
+        softcap=cfg.attn_softcap, window=window, scale=_scale(cfg),
         return_stats=return_stats, impl=rt.kernel_impl,
         pages_per_chunk=rt.paged_chunk)
     if return_stats:
@@ -225,7 +231,8 @@ def attn_decode_paged_striped(params, x, cfg, rt: Runtime, ctx, *,
             jnp.where(own2, vn.reshape(bb, -1).astype(pv.dtype) - cur_v, 0))
         o, (m, l) = ops.paged_attention(
             qb, pk, pv, local_table, ctxl + 1, softcap=cfg.attn_softcap,
-            window=window, page_mask=owned, return_stats=True,
+            window=window, page_mask=owned, scale=_scale(cfg),
+            return_stats=True,
             impl=_masked_impl(rt), pages_per_chunk=rt.paged_chunk)
         outs = jax.lax.all_gather(o.astype(jnp.float32), combine_axes)
         ms = jax.lax.all_gather(m, combine_axes)
@@ -266,7 +273,7 @@ def attn_decode_dense(params, x, cfg, rt: Runtime, *, cache_k, cache_v,
     h = q.shape[2]
     kv = kf.shape[2]
     qg = q[:, 0].astype(jnp.float32).reshape(b, kv, h // kv, -1)
-    qg = qg * (1.0 / jnp.sqrt(jnp.float32(q.shape[-1])))
+    qg = qg * (_scale(cfg) or (1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))))
     s = jnp.einsum("bkgd,bskd->bkgs", qg, kf)
     s = common.softcap(s, cfg.attn_softcap)
     valid = jnp.arange(smax)[None, :] <= ctx_lens[:, None]

@@ -85,6 +85,8 @@ class Model:
         x = params["embed"][tokens].astype(self.rt.compute_dtype)
         if self.cfg.name.startswith("gemma"):
             x = x * jnp.asarray(math.sqrt(self.cfg.d_model), x.dtype)
+        if self.cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(self.cfg.embedding_multiplier, x.dtype)
         return x
 
     def _fuse_inputs(self, params, batch):
@@ -122,8 +124,10 @@ class Model:
             logits = x @ common.cast(params["embed"], cd).T
         else:
             logits = x @ common.cast(params["head"], cd)
-        return common.softcap(logits.astype(jnp.float32),
-                              self.cfg.final_softcap)
+        logits = logits.astype(jnp.float32)
+        if self.cfg.logits_scaling != 1.0:
+            logits = logits / self.cfg.logits_scaling
+        return common.softcap(logits, self.cfg.final_softcap)
 
     # ------------------------------------------------------------------
     def loss_fn(self, params, batch) -> Tuple[jnp.ndarray, Dict[str, Any]]:
@@ -164,7 +168,7 @@ class Model:
         x, _, caches = transformer.stack_forward(
             params["stack"], x, self.cfg, self.rt, self.ctx,
             positions=positions, enc_out=enc_out, src_valid=src_valid,
-            collect_caches=True)
+            collect_caches=True, dropless=True)
         x = common.rms_norm(x, params["final_norm"], self.cfg.norm_eps)
         last = x[:, -1]
         return self._logits(params, last), caches

@@ -12,6 +12,16 @@ routing/sort work is shard-local (no global argsort collectives).
 Token slots beyond an expert's capacity are dropped (standard static
 -capacity semantics); Runtime.capacity_factor scales C (tests use a
 large factor to verify the dropless limit equals the dense reference).
+That is the training path. Serving (``dropless=True``: prefill and
+decode) drops nothing: the router picks top_k of all n_experts, then
+softmax over the picked logits (the same weights as softmax, top-k and
+renormalise); the assignments to the experts this layer holds
+(MoEConfig.held_offset, .held) are sorted by expert and run as one
+grouped matmul per projection (ops.grouped_matmul: megablox ``gmm`` on
+the TPU), and the rest are left to the chips that hold those experts.
+
+A shared expert (MoEConfig.shared_d_ff, granite) is a SwiGLU MLP that
+every token runs, added once beside the routed experts.
 """
 from __future__ import annotations
 
@@ -23,33 +33,43 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import ops
 from repro.models import common
 from repro.parallel.sharding import shard_map
 from repro.models.common import Runtime
 
 
 def init_moe(key, cfg, dtype):
+    """Router over all n_experts (fp32); the held experts' weights."""
+    from repro.models import mlp
     m = cfg.moe
-    d, ff, e = cfg.d_model, m.d_ff, m.n_experts
+    d, ff, e = cfg.d_model, m.d_ff, m.held
     ks = jax.random.split(key, 4)
     std = 1.0 / math.sqrt(d)
     params = {
-        "router": common.init_dense(ks[0], d, e, jnp.float32),  # fp32 router
+        "router": common.init_dense(ks[0], d, m.n_experts, jnp.float32),
         "wg": (jax.random.normal(ks[1], (e, d, ff), jnp.float32) * std).astype(dtype),
         "wu": (jax.random.normal(ks[2], (e, d, ff), jnp.float32) * std).astype(dtype),
         "wd": (jax.random.normal(ks[3], (e, ff, d), jnp.float32)
                * (1.0 / math.sqrt(ff))).astype(dtype),
     }
+    if m.shared_d_ff:
+        params["shared"] = mlp.init_mlp(jax.random.fold_in(key, 4), cfg,
+                                        dtype, d_ff=m.shared_d_ff)
     return params
 
 
 def moe_specs(cfg):
-    return {
+    from repro.models import mlp
+    specs = {
         "router": P(None, None),
         "wg": P("model", None, None),
         "wu": P("model", None, None),
         "wd": P("model", None, None),
     }
+    if cfg.moe.shared_d_ff:
+        specs["shared"] = mlp.mlp_specs(cfg)
+    return specs
 
 
 def _capacity(tokens: int, n_experts: int, top_k: int, factor: float) -> int:
@@ -71,7 +91,7 @@ def _moe_local(x, router, wg, wu, wd, *, cfg, rt: Runtime, tp_axis: str,
     topv, topi = jax.lax.top_k(gates, k)                               # [Tl,k]
     topv = topv / jnp.maximum(topv.sum(axis=-1, keepdims=True), 1e-9)
 
-    e0 = jax.lax.axis_index(tp_axis) * el
+    e0 = m.held_offset + jax.lax.axis_index(tp_axis) * el
     flat_e = topi.reshape(-1)                                          # [Tl*k]
     flat_w = topv.reshape(-1)
     flat_t = jnp.repeat(jnp.arange(tl), k)
@@ -79,19 +99,7 @@ def _moe_local(x, router, wg, wu, wd, *, cfg, rt: Runtime, tp_axis: str,
     is_local = (local_e >= 0) & (local_e < el)
     le = jnp.where(is_local, local_e, el)                              # el = drop bucket
 
-    # stable argsort by expert == sort of the packed key le*(Tl*k)+slot:
-    # one single-operand int32 sort instead of the (keys, iota) variadic
-    # comparator sort argsort lowers to (~7x slower on XLA CPU; same
-    # packing trick as core/fmmu/batch._insert_blocks)
-    nk = tl * k
-    if (el + 1) * nk < 2 ** 31:
-        skey = jnp.sort(le.astype(jnp.int32) * nk
-                        + jnp.arange(nk, dtype=jnp.int32))
-        order = jnp.mod(skey, nk)
-        sle = skey // nk
-    else:                                  # huge shards: packing overflows
-        order = jnp.argsort(le, stable=True)
-        sle = le[order]
+    order, sle = _sort_by_expert(le, el)
     counts = jnp.bincount(sle, length=el + 1)
     offsets = jnp.cumsum(counts) - counts
     pos = jnp.arange(tl * k) - offsets[sle]
@@ -130,8 +138,61 @@ def _moe_local(x, router, wg, wu, wd, *, cfg, rt: Runtime, tp_axis: str,
     return out.astype(x.dtype), aux
 
 
-def apply_moe(params, x, cfg, rt: Runtime, ctx, *, dense_params=None):
-    """x [B,S,d] -> ([B,S,d], aux_loss scalar). ctx: ParallelCtx."""
+def _sort_by_expert(le, el: int):
+    """Stable argsort of the local expert ids ``le`` (el = not held),
+    and the sorted ids. A sort of the packed key le*n+slot: one
+    single-operand int32 sort instead of the (keys, iota) variadic
+    comparator sort argsort lowers to (~7x slower on XLA CPU; same
+    packing trick as core/fmmu/batch._insert_blocks)."""
+    nk = le.shape[0]
+    if (el + 1) * nk < 2 ** 31:
+        skey = jnp.sort(le.astype(jnp.int32) * nk
+                        + jnp.arange(nk, dtype=jnp.int32))
+        return jnp.mod(skey, nk), skey // nk
+    order = jnp.argsort(le, stable=True)   # huge shards: packing overflows
+    return order, le[order]
+
+
+def _moe_dropless_local(x, router, wg, wu, wd, *, cfg, rt: Runtime,
+                        tp_axis: str):
+    """Per-shard dropless body (runs under shard_map): this shard's held
+    experts' part of the routed output, f32 [Tl, d]; no aux loss."""
+    m = cfg.moe
+    tl, d = x.shape
+    el, k, cd = wg.shape[0], m.top_k, rt.compute_dtype
+    with jax.named_scope("moe_route"):
+        logits = x.astype(jnp.float32) @ router                        # [Tl,E]
+        topl, topi = jax.lax.top_k(logits, k)
+        topv = jax.nn.softmax(topl, axis=-1)
+        local = topi.reshape(-1) - (m.held_offset
+                                    + jax.lax.axis_index(tp_axis) * el)
+        le = jnp.where((local >= 0) & (local < el), local, el)
+        order, sle = _sort_by_expert(le, el)
+        # a token's k experts are distinct, so at most min(k, el) of its
+        # assignments are held here, and they sort first
+        n_rows = tl * min(k, el)
+        order, sle = order[:n_rows], sle[:n_rows]
+        sizes = jnp.bincount(le, length=el + 1)[:el].astype(jnp.int32)
+        tok = order // k
+        rows = x[tok].astype(cd)
+    with jax.named_scope("moe_experts"):
+        gmm = functools.partial(ops.grouped_matmul, group_sizes=sizes,
+                                impl=rt.kernel_impl)
+        h = common.activation(gmm(rows, common.cast(wg, cd)), cfg.act) \
+            * gmm(rows, common.cast(wu, cd))
+        y = gmm(h, common.cast(wd, cd))
+        keep = sle < el          # rows past the held groups are not written
+        w = jnp.where(keep, topv.reshape(-1)[order], 0.0)
+        y = jnp.where(keep[:, None], y.astype(jnp.float32), 0.0) * w[:, None]
+        out = jnp.zeros((tl, d), jnp.float32).at[tok].add(y)
+    return jax.lax.psum(out, tp_axis).astype(x.dtype), jnp.float32(0.0)
+
+
+def apply_moe(params, x, cfg, rt: Runtime, ctx, *, dense_params=None,
+              dropless: bool = False):
+    """x [B,S,d] -> ([B,S,d], aux_loss scalar). ctx: ParallelCtx.
+    ``dropless`` is the serving path (module docstring); otherwise the
+    static-capacity training path."""
     from repro.models import mlp as mlp_mod
     m = cfg.moe
     b, s, d = x.shape
@@ -140,11 +201,15 @@ def apply_moe(params, x, cfg, rt: Runtime, ctx, *, dense_params=None):
     shard_tokens = (b % ctx.dp_size) == 0
     dp_axes = tuple(ctx.dp) if shard_tokens else ()
     tl = (b // ctx.dp_size if shard_tokens else b) * s
-    cf = rt.capacity_factor if rt.capacity_factor is not None else m.capacity_factor
-    capacity = _capacity(tl, m.n_experts, m.top_k, cf)
-
-    body = functools.partial(_moe_local, cfg=cfg, rt=rt, tp_axis=ctx.tp,
-                             dp_axes=dp_axes, capacity=capacity)
+    if dropless:
+        body = functools.partial(_moe_dropless_local, cfg=cfg, rt=rt,
+                                 tp_axis=ctx.tp)
+    else:
+        cf = (rt.capacity_factor if rt.capacity_factor is not None
+              else m.capacity_factor)
+        capacity = _capacity(tl, m.n_experts, m.top_k, cf)
+        body = functools.partial(_moe_local, cfg=cfg, rt=rt, tp_axis=ctx.tp,
+                                 dp_axes=dp_axes, capacity=capacity)
     if shard_tokens:
         dp_spec = dp_axes if len(dp_axes) > 1 else dp_axes[0]
     else:
@@ -160,13 +225,18 @@ def apply_moe(params, x, cfg, rt: Runtime, ctx, *, dense_params=None):
     out, aux = fn(x2, params["router"], params["wg"], params["wu"],
                   params["wd"])
     out = out.reshape(b, s, d)
+    if "shared" in params:
+        with jax.named_scope("shared_expert"):
+            out = out + mlp_mod.apply_mlp(params["shared"], x, cfg, rt)
     if dense_params is not None:  # arctic: parallel dense residual MLP
         out = out + mlp_mod.apply_mlp(dense_params, x, cfg, rt)
     return out, aux * m.router_aux_weight
 
 
 def apply_moe_dense_ref(params, x, cfg, rt: Runtime):
-    """O(T*E) dense reference (tests): every expert runs every token."""
+    """O(T*E) dense reference (tests): every held expert runs every
+    token; the shared expert is added once."""
+    from repro.models import mlp as mlp_mod
     m = cfg.moe
     cd = rt.compute_dtype
     b, s, d = x.shape
@@ -175,9 +245,13 @@ def apply_moe_dense_ref(params, x, cfg, rt: Runtime):
     topv, topi = jax.lax.top_k(gates, m.top_k)
     topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
     full = jnp.zeros_like(gates).at[jnp.arange(x2.shape[0])[:, None], topi].set(topv)
+    full = full[:, m.held_offset:m.held_offset + m.held]
     h = common.activation(jnp.einsum("td,edf->tef", x2.astype(cd),
                                      common.cast(params["wg"], cd)), cfg.act)
     h = h * jnp.einsum("td,edf->tef", x2.astype(cd), common.cast(params["wu"], cd))
     y = jnp.einsum("tef,efd->ted", h, common.cast(params["wd"], cd))
     out = jnp.einsum("ted,te->td", y.astype(jnp.float32), full)
-    return out.reshape(b, s, d).astype(x.dtype)
+    out = out.reshape(b, s, d).astype(x.dtype)
+    if "shared" in params:
+        out = out + mlp_mod.apply_mlp(params["shared"], x, cfg, rt)
+    return out

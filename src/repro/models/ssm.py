@@ -132,8 +132,10 @@ def ssm_init_state(cfg, batch: int, dtype):
     return conv_state, ssm_state
 
 
-def ssm_decode(params, x, state, cfg, rt: Runtime):
-    """One-token decode. x [B,d]; state=(conv_state, ssm_state)."""
+def ssm_decode(params, x, state, cfg, rt: Runtime, layer):
+    """One-token decode. x [B,d]; state=(conv_state, ssm_state), the
+    decode scan's stacks [L, B, ...]; ``layer`` is the one this call
+    reads and updates (in place: the ``ssm_state`` scope)."""
     s = cfg.ssm
     b, d = x.shape
     di = s.d_inner(d)
@@ -142,16 +144,19 @@ def ssm_decode(params, x, state, cfg, rt: Runtime):
     conv_state, ssm_state = state
     xb, z, bv, cv, dt = _project(params, x[:, None, :], cfg, rt)
     conv_in = jnp.concatenate([xb[:, 0], bv[:, 0], cv[:, 0]], axis=-1)
-    conv_out, conv_state = _conv_step(
-        conv_state, conv_in, params["conv_w"].astype(rt.compute_dtype),
+    conv_out, conv_new = _conv_step(
+        conv_state[layer], conv_in,
+        params["conv_w"].astype(rt.compute_dtype),
         params["conv_b"].astype(rt.compute_dtype))
     xb1, bv1, cv1 = (conv_out[:, :di], conv_out[:, di:di + n],
                      conv_out[:, di + n:])
     dtv = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + params["dt_bias"][None, :])
     A = -jnp.exp(params["A_log"])
-    y, ssm_state = ops.mamba_decode_step(
-        ssm_state, xb1.reshape(b, nh, s.head_dim), dtv, A, bv1, cv1,
-        params["D"])
+    with jax.named_scope("ssm_state"):
+        y, ssm_state = ops.mamba_decode_step(
+            ssm_state, xb1.reshape(b, nh, s.head_dim), dtv, A, bv1, cv1,
+            params["D"], layer=layer, impl=rt.kernel_impl)
+        conv_state = conv_state.at[layer].set(conv_new)
     y = y.reshape(b, di)
     y = common.rms_norm(y * jax.nn.silu(z[:, 0].astype(jnp.float32)).astype(y.dtype),
                         params["norm"], cfg.norm_eps)

@@ -93,12 +93,19 @@ def stack_specs(cfg, *, cross: bool = False):
     return common.stacked_specs(period_specs)
 
 
+def _residual(x, y, cfg):
+    """x plus the branch y, scaled by granite's residual_multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return x + y
+
+
 # ----------------------------------------------------------------------
 # forward (train / prefill)
 # ----------------------------------------------------------------------
 def _apply_layer_full(lp, x, cfg, rt: Runtime, ctx, j: int, *, positions,
                       segment_ids, bidirectional, enc_out, src_valid,
-                      collect):
+                      collect, dropless):
     """One layer, full-sequence. Returns (x, aux, collected)."""
     aux = jnp.float32(0.0)
     col: Dict[str, Any] = {}
@@ -124,7 +131,7 @@ def _apply_layer_full(lp, x, cfg, rt: Runtime, ctx, j: int, *, positions,
             y = ssm.ssm_forward(lp["mixer"], h, cfg, rt)
     if cfg.post_norms:
         y = common.rms_norm(y, lp["post1"], cfg.norm_eps)
-    x = x + y
+    x = _residual(x, y, cfg)
     if enc_out is not None and "cross" in lp:
         h = common.rms_norm(x, lp["cross_ln"], cfg.norm_eps)
         kv = attention.cross_kv(lp["cross"], enc_out, cfg, rt)
@@ -138,22 +145,23 @@ def _apply_layer_full(lp, x, cfg, rt: Runtime, ctx, j: int, *, positions,
         if "moe" in lp["ffn"]:
             y, aux = moe.apply_moe(
                 lp["ffn"]["moe"], h, cfg, rt, ctx,
-                dense_params=lp["ffn"].get("dense"))
+                dense_params=lp["ffn"].get("dense"), dropless=dropless)
         else:
             y = mlp.apply_mlp(lp["ffn"]["dense"], h, cfg, rt)
         if cfg.post_norms:
             y = common.rms_norm(y, lp["post2"], cfg.norm_eps)
-        x = x + y
+        x = _residual(x, y, cfg)
     return x, aux, col
 
 
 def stack_forward(params, x, cfg, rt: Runtime, ctx, *, positions,
                   segment_ids=None, bidirectional=False, enc_out=None,
-                  src_valid=None, collect_caches=False):
+                  src_valid=None, collect_caches=False, dropless=False):
     """Full stack. Returns (x, aux_total, caches or None).
 
     caches (when collect_caches): pytree of per-period stacked collections
     — leaves [n_periods, ...] with a per-period list over attn/ssm layers.
+    ``dropless``: the MoE layers' serving path (models/moe.py).
     """
     period = cfg.period
 
@@ -165,7 +173,8 @@ def stack_forward(params, x, cfg, rt: Runtime, ctx, *, positions,
                 pp[j], xc, cfg, rt, ctx, j,
                 positions=positions, segment_ids=segment_ids,
                 bidirectional=bidirectional, enc_out=enc_out,
-                src_valid=src_valid, collect=collect_caches)
+                src_valid=src_valid, collect=collect_caches,
+                dropless=dropless)
             auxc = auxc + aux_j
             cols.append(col)
         return (xc, auxc), cols
@@ -236,22 +245,25 @@ def stack_decode(params, x, caches, cfg, rt: Runtime, ctx, *, ctx_lens,
     carried stack in place and paged attention reads the stack through
     the layer's index, so no layer's pool is sliced out or written back
     whole, and a scan of decode steps around this one keeps the pool in
-    one buffer. The per-lane caches (conv, ssm, cross) are small and
-    stay in the scan's xs / ys."""
+    one buffer. The Mamba layers' conv and SSM states ride the carry
+    the same way, [n_periods * n_mamba, B, ...], and the state kernel
+    updates its layer in place. Cross-attention caches, which decode
+    only reads, stay in the scan's xs / ys. MoE layers take the
+    dropless serving path."""
     period = cfg.period
     n_periods = cfg.n_layers // period
     attn_js = [j for j in range(period) if cfg.layer_kind(j) == "attn"]
     ssm_js = [j for j in range(period) if cfg.layer_kind(j) == "mamba"]
     a_of = {j: i for i, j in enumerate(attn_js)}
     s_of = {j: i for i, j in enumerate(ssm_js)}
-    pools = {k: caches[k].reshape(-1, *caches[k].shape[2:])
-             for k in ("pool_k", "pool_v") if k in caches}
-    small = {k: v for k, v in caches.items() if k not in pools}
+    stacks = {k: caches[k].reshape(-1, *caches[k].shape[2:])
+             for k in ("pool_k", "pool_v", "conv", "ssm") if k in caches}
+    small = {k: v for k, v in caches.items() if k not in stacks}
 
     def body(carry, scanned):
-        xc, pools = carry
+        xc, stacks = carry
         pidx, pp, cc = scanned
-        pools, new_cc = dict(pools), dict(cc)
+        stacks, new_cc = dict(stacks), dict(cc)
         for j in range(period):
             lp = pp[j]
             h = common.rms_norm(xc, lp["ln1"], cfg.norm_eps)
@@ -261,33 +273,30 @@ def stack_decode(params, x, caches, cfg, rt: Runtime, ctx, *, ctx_lens,
                     # the striped kernel runs under shard_map on one
                     # layer's pool: slice it and write it back
                     with jax.named_scope("kv_pool"):
-                        pool_k = pools["pool_k"][li]
-                        pool_v = pools["pool_v"][li]
+                        pool_k = stacks["pool_k"][li]
+                        pool_v = stacks["pool_v"][li]
                     y, pk, pv = attention.attn_decode_paged_striped(
                         lp["mixer"], h, cfg, rt, ctx,
                         pool_k=pool_k, pool_v=pool_v,
                         block_table=block_table, ctx_lens=ctx_lens,
                         kind=cfg.attn_kind(j))
                     with jax.named_scope("kv_pool"):
-                        pools["pool_k"] = pools["pool_k"].at[li].set(pk)
-                        pools["pool_v"] = pools["pool_v"].at[li].set(pv)
+                        stacks["pool_k"] = stacks["pool_k"].at[li].set(pk)
+                        stacks["pool_v"] = stacks["pool_v"].at[li].set(pv)
                 else:
-                    y, pools["pool_k"], pools["pool_v"] = \
+                    y, stacks["pool_k"], stacks["pool_v"] = \
                         attention.attn_decode_paged(
                             lp["mixer"], h, cfg, rt,
-                            pool_k=pools["pool_k"], pool_v=pools["pool_v"],
+                            pool_k=stacks["pool_k"], pool_v=stacks["pool_v"],
                             block_table=block_table, ctx_lens=ctx_lens,
                             kind=cfg.attn_kind(j), layer=li)
             else:
-                si = s_of[j]
-                y, (cs, ss) = ssm.ssm_decode(
-                    lp["mixer"], h, (new_cc["conv"][si], new_cc["ssm"][si]),
-                    cfg, rt)
-                new_cc["conv"] = new_cc["conv"].at[si].set(cs)
-                new_cc["ssm"] = new_cc["ssm"].at[si].set(ss)
+                y, (stacks["conv"], stacks["ssm"]) = ssm.ssm_decode(
+                    lp["mixer"], h, (stacks["conv"], stacks["ssm"]), cfg, rt,
+                    layer=pidx * len(ssm_js) + s_of[j])
             if cfg.post_norms:
                 y = common.rms_norm(y, lp["post1"], cfg.norm_eps)
-            xc = xc + y
+            xc = _residual(xc, y, cfg)
             if "cross" in lp:
                 h = common.rms_norm(xc, lp["cross_ln"], cfg.norm_eps)
                 y3 = attention.cross_forward(
@@ -300,27 +309,28 @@ def stack_decode(params, x, caches, cfg, rt: Runtime, ctx, *, ctx_lens,
                 if "moe" in lp["ffn"]:
                     y2, _ = moe.apply_moe(lp["ffn"]["moe"], h[:, None, :],
                                           cfg, rt, ctx,
-                                          dense_params=lp["ffn"].get("dense"))
+                                          dense_params=lp["ffn"].get("dense"),
+                                          dropless=True)
                     y2 = y2[:, 0]
                 else:
                     y2 = mlp.apply_mlp(lp["ffn"]["dense"], h[:, None, :],
                                        cfg, rt)[:, 0]
                 if cfg.post_norms:
                     y2 = common.rms_norm(y2, lp["post2"], cfg.norm_eps)
-                xc = xc + y2
-        return (xc, pools), new_cc
+                xc = _residual(xc, y2, cfg)
+        return (xc, stacks), new_cc
 
     if rt.scan_layers:
-        (x, pools), small = jax.lax.scan(
-            body, (x, pools),
+        (x, stacks), small = jax.lax.scan(
+            body, (x, stacks),
             (jnp.arange(n_periods, dtype=jnp.int32), params, small))
     else:
         outs = []
         for pidx in range(n_periods):
             pp = jax.tree.map(lambda t: t[pidx], params)
             cc = jax.tree.map(lambda t: t[pidx], small)
-            (x, pools), ncc = body((x, pools), (pidx, pp, cc))
+            (x, stacks), ncc = body((x, stacks), (pidx, pp, cc))
             outs.append(ncc)
         small = common.tree_stack(outs)
     return x, {**small,
-               **{k: v.reshape(caches[k].shape) for k, v in pools.items()}}
+               **{k: v.reshape(caches[k].shape) for k, v in stacks.items()}}

@@ -10,6 +10,7 @@ from repro.kernels import ref
 from repro.kernels import flash_attention as fa
 from repro.kernels import paged_attention as pa
 from repro.kernels import mamba_scan as ms
+from repro.kernels import ssm_step
 from repro.kernels import fmmu_lookup as fl
 
 
@@ -217,6 +218,76 @@ def test_mamba_scan_initial_state():
                                         initial_state=s0)
     np.testing.assert_allclose(y, yw, atol=5e-3, rtol=5e-3)
     np.testing.assert_allclose(fin, fw, atol=5e-3, rtol=5e-3)
+
+
+def _ssm_step_args(L, bt, h, p, n):
+    k = jax.random.key(11)
+    f = lambda i, shape: jax.random.normal(jax.random.fold_in(k, i), shape)
+    return (f(0, (L, bt, h, p, n)), f(1, (bt, h, p)).astype(jnp.bfloat16),
+            jax.nn.softplus(f(2, (bt, h))) * 0.1, -jnp.exp(f(3, (h,))),
+            f(4, (bt, n)).astype(jnp.bfloat16),
+            f(5, (bt, n)).astype(jnp.bfloat16), 1.0 + 0.1 * f(6, (h,)))
+
+
+@pytest.mark.parametrize("bt,h,p,n", [
+    (2, 8, 16, 32),
+    (2, 128, 64, 128),          # granite-4.0-h-small's heads
+])
+@pytest.mark.parametrize("layer", [None, 0, 2])
+def test_ssm_step_kernel_matches_ref(bt, h, p, n, layer):
+    """_ssm_step_kernel (interpret) against its ref lowering, on one
+    layer's state and on a stack of three with a layer index; the other
+    layers of the stack come back untouched. f32 state; the kernel sums
+    y over N in another order than the einsum: 1e-5."""
+    st, x, dt, A, B, C, D = _ssm_step_args(3, bt, h, p, n)
+    if layer is None:
+        st = st[1]
+    y, got = ssm_step.ssm_step(st, x, dt, A, B, C, D, layer=layer,
+                               interpret=True)
+    yw, want = ref.mamba_decode_step(st, x, dt, A, B, C, D, layer=layer)
+    assert got.shape == st.shape and y.dtype == x.dtype
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(y.astype(np.float32), yw.astype(np.float32),
+                               atol=1e-2, rtol=1e-2)   # both rounded to bf16
+    if layer is not None:
+        others = [i for i in range(3) if i != layer]
+        np.testing.assert_array_equal(np.asarray(got)[others],
+                                      np.asarray(st)[others])
+        assert not np.allclose(got[layer], st[layer])
+
+
+@pytest.mark.parametrize("kernel", ["paged", "flash"])
+def test_attention_kernels_take_a_scale(kernel):
+    """A q.k multiplier other than 1/sqrt(D) (granite: 1/128 at D=128)
+    reaches _pa_kernel and _fa_kernel and their ref lowerings alike; the
+    default is 1/sqrt(D). f32: 2e-5, as the other kernel tests."""
+    k = jax.random.key(12)
+    d, scale = 128, 1.0 / 128
+    f = lambda i, shape: jax.random.normal(jax.random.fold_in(k, i), shape)
+    if kernel == "paged":
+        b, h, kv, page, maxp = 2, 4, 2, 8, 4
+        nb = b * maxp
+        q = f(1, (b, h, d))
+        kp, vp = f(2, (nb, page, kv, d)), f(3, (nb, page, kv, d))
+        table = jnp.arange(nb, dtype=jnp.int32).reshape(b, maxp)
+        ctx = jnp.asarray([13, 29], jnp.int32)
+        run = lambda sc: pa.paged_attention(
+            q, kp.reshape(nb, page, kv * d), vp.reshape(nb, page, kv * d),
+            table, ctx, scale=sc, interpret=True)
+        want = lambda sc: ref.paged_attention_naive(q, kp, vp, table, ctx,
+                                                    scale=sc)
+    else:
+        q, kk, v = f(1, (1, 64, 4, d)), f(2, (1, 64, 2, d)), f(3, (1, 64, 2, d))
+        run = lambda sc: fa.flash_attention(q, kk, v, causal=True, scale=sc,
+                                            q_block=32, kv_block=32,
+                                            interpret=True)
+        want = lambda sc: ref.attention_naive(q, kk, v, causal=True,
+                                              scale=sc)
+    got = run(scale)
+    np.testing.assert_allclose(got, want(scale), atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(run(None), want(1.0 / np.sqrt(d)),
+                               atol=2e-5, rtol=2e-5)
+    assert not np.allclose(got, run(None), atol=1e-3)
 
 
 # ----------------------------------------------------------------------

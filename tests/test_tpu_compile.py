@@ -127,6 +127,49 @@ def test_flash_attention_prefill_compiles_at_1024_tokens(one_chip):
              S((1, 1024, KV, D), jnp.bfloat16))
 
 
+def test_ssm_step_compiles_at_granite_widths(one_chip):
+    """The Mamba-2 decode state kernel on granite-4.0-h-small's carried
+    stack (9 Mamba layers x 64 lanes x 128 heads x 64 x 128, f32),
+    updated in place: the stack is aliased, and nothing is copied."""
+    from repro.kernels import ssm_step
+    S = _sds(one_chip)
+    compiled = jax.jit(lambda st, x, dt, a, b, c, d, ly: ssm_step.ssm_step(
+        st, x, dt, a, b, c, d, layer=ly), donate_argnums=(0,)).lower(
+        S((9, 64, 128, 64, 128), jnp.float32),
+        S((64, 128, 64), jnp.bfloat16), S((64, 128), jnp.float32),
+        S((128,), jnp.float32), S((64, 128), jnp.bfloat16),
+        S((64, 128), jnp.bfloat16), S((128,), jnp.float32),
+        S((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 9 * 64 * 128 * 64 * 128 * 4
+    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+
+
+def test_mamba_scan_compiles_at_granite_prefill(one_chip):
+    """The chunked Mamba-2 scan over a 2048-token prompt at
+    granite-4.0-h-small's heads (128 x 64, d_state 128, chunk 256)."""
+    from repro.kernels import mamba_scan as ms
+    S = _sds(one_chip)
+    _compile(lambda x, dt, a, b, c, d: ms.mamba_chunk_scan(
+        x, dt, a, b, c, d, chunk=256),
+        S((1, 2048, 128, 64), jnp.bfloat16), S((1, 2048, 128), jnp.float32),
+        S((128,), jnp.float32), S((1, 2048, 128), jnp.bfloat16),
+        S((1, 2048, 128), jnp.bfloat16), S((128,), jnp.float32))
+
+
+@pytest.mark.parametrize("k,n", [(4096, 768), (768, 4096)])
+def test_grouped_matmul_compiles_at_granite_decode(one_chip, k, n):
+    """The expert grouped matmul (megablox gmm) of one decode step:
+    64 lanes x top-10 rows over 18 held experts, up/gate and down."""
+    from repro.kernels import ops
+    S = _sds(one_chip)
+    _compile(lambda lhs, rhs, sizes: ops.grouped_matmul(
+        lhs, rhs, sizes, impl="pallas"),
+        S((640, k), jnp.bfloat16), S((18, k, n), jnp.bfloat16),
+        S((18,), jnp.int32))
+
+
 @pytest.mark.parametrize("call", ["translate", "retranslate"])
 def test_sharded_map_translate_compiles_on_four_chips(topo, no_cache,
                                                      pallas_impl, call):
