@@ -341,7 +341,8 @@ class ServeEngine:
                         "recoveries": 0, "gc_walks": 0, "gc_moves": 0,
                         "gc_victims": 0, "shared_admits": 0,
                         "shared_pages": 0, "cow_moves": 0,
-                        "host_syncs": 0}
+                        "host_syncs": 0, "pa_live_pages": 0,
+                        "pa_bucket_pages": 0}
         # crash-consistency journal (ISSUE 7, core/journal.py): when
         # attached, every host commit point appends a sequence-numbered
         # record and every `snapshot_every`-th macro boundary writes a
@@ -1137,6 +1138,14 @@ class ServeEngine:
             p *= 2
         return min(p, self.max_pages)
 
+    def _count_pages(self, live_pages: int, n_lanes: int, pages: int):
+        """One decode dispatch's paged-attention engagement: the pages
+        its resident lanes hold at scan end (what the kernel copies, a
+        window aside) and lanes x the page bucket (what a grid of one
+        page a step over the bucket copied)."""
+        self.metrics["pa_live_pages"] += int(live_pages)
+        self.metrics["pa_bucket_pages"] += n_lanes * pages
+
     def _table_grid(self, table, pages):
         """Flat (or [C, L] channel-sharded) incremental table ->
         [n_slots, <=pages] global grid: ``fb.interleave_table`` (the
@@ -1253,8 +1262,9 @@ class ServeEngine:
         # numpy args go straight to the jit (its shard_args transfer is
         # cheaper than an explicit device_put per array); the only
         # per-step host sync is the next_tok readback
-        pages = self._page_bucket(max(
-            len(self.kvm.seq_pages[r.slot]) for r in residents))
+        held = [len(self.kvm.seq_pages[r.slot]) for r in residents]
+        pages = self._page_bucket(max(held))
+        self._count_pages(sum(held), len(residents), pages)
         with span("serve.dispatch"):
             next_tok, self.caches = self._decode(
                 self.params, tokens, self.caches, self.ctx_lens,
@@ -1610,13 +1620,14 @@ class ServeEngine:
             # live-page bucket: worst-case pages any slot can hold by scan
             # end (exact post-schedule count in simple mode)
             if simple:
-                pages = self._page_bucket(int(npages[alive].max()))
+                end = npages
             else:
                 end = np.minimum(
                     self.max_pages,
                     np.maximum(npages, (self.ctx_lens + self.macro_k
                                         + self.page - 1) // self.page))
-                pages = self._page_bucket(int(end[alive].max()))
+            pages = self._page_bucket(int(end[alive].max()))
+            self._count_pages(end[alive].sum(), int(alive.sum()), pages)
         MACRO_DISPATCHES[0] += 1
         # steady state (no lane mid-prompt) uses the forced=None trace:
         # the scan carries zero admission machinery
@@ -1815,6 +1826,7 @@ class ServeEngine:
         simple = self.eos_id < 0 and bool(
             (budget[alive] >= gen[alive]).all())
         pages = self._page_bucket(int(npg[alive].max()))
+        self._count_pages(npg[alive].sum(), int(alive.sum()), pages)
         MACRO_DISPATCHES[0] += 1
         forced = (fmask, ftok, emit) if pend.any() else None
         if simple:

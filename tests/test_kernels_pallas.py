@@ -70,7 +70,39 @@ PAGED_SHAPES = [
     (2, 4, 4, 32, 16, 8),
     (3, 8, 2, 64, 8, 6),      # GQA
     (1, 4, 1, 128, 32, 4),
+    (5, 32, 16, 128, 16, 16),  # several blocks a lane: _paged_ctx's edges
 ]
+STACKED_CASES = [
+    pytest.param(*shape, jnp.float32, which,
+                 id="-".join(map(str, (which, *shape))))
+    for shape in PAGED_SHAPES for which in ("first", "middle", "last")] + [
+    pytest.param(*PAGED_SHAPES[-1], jnp.bfloat16, "last",
+                 id="-".join(map(str, ("last", *PAGED_SHAPES[-1],
+                                       "bfloat16"))))]
+
+
+def _paged_ctx(b, page, maxp, kvd, dtype):
+    """Contexts of the b lanes: spread over the bucket, or with five
+    lanes the kernel's edges — a dead lane (0), 1, exactly one block,
+    one past a block boundary, and the full bucket."""
+    if b != 5:
+        return jnp.asarray([(maxp * page * (i + 1)) // (b + 1) + 1
+                            for i in range(b)], jnp.int32)
+    ppb = pa.pages_per_block(page, kvd, jnp.dtype(dtype).itemsize, maxp)
+    assert 2 * ppb <= maxp, "the bucket must hold two blocks"
+    return jnp.asarray([0, 1, ppb * page, ppb * page + 1, maxp * page],
+                       jnp.int32)
+
+
+def _assert_dead_lanes(ctx, out, m, l):
+    """A lane with no context reads nothing: output 0, l 0, m -inf
+    (what the flash-decoding combine weighs as no keys at all). The
+    references attend the masked bucket uniformly there instead."""
+    dead = np.asarray(ctx) == 0
+    assert (np.asarray(out, np.float32)[dead] == 0).all()
+    assert (np.asarray(l)[dead] == 0).all()
+    assert (np.asarray(m)[dead] == pa.NEG_INF).all()
+    return ~dead
 
 
 @pytest.mark.parametrize("b,h,kv,d,page,maxp", PAGED_SHAPES)
@@ -83,23 +115,23 @@ def test_paged_attention_shapes(b, h, kv, d, page, maxp, dtype):
     vp = jax.random.normal(jax.random.fold_in(k, 3), (nb, page, kv, d), dtype)
     table = jax.random.permutation(
         jax.random.fold_in(k, 4), jnp.arange(nb))[:b * maxp].reshape(b, maxp)
-    ctx = jnp.asarray([(maxp * page * (i + 1)) // (b + 1) + 1
-                       for i in range(b)], jnp.int32)
+    ctx = _paged_ctx(b, page, maxp, kv * d, dtype)
     out, (m, l) = pa.paged_attention(q, kp.reshape(nb, page, kv * d),
                                      vp.reshape(nb, page, kv * d), table,
                                      ctx, return_stats=True, interpret=True)
     want, (wm, wl) = ref.paged_attention_naive(q, kp, vp, table, ctx,
                                                return_stats=True)
-    np.testing.assert_allclose(out.astype(np.float32),
-                               want.astype(np.float32),
+    live = _assert_dead_lanes(ctx, out, m, l)
+    np.testing.assert_allclose(out.astype(np.float32)[live],
+                               want.astype(np.float32)[live],
                                atol=TOL[dtype], rtol=TOL[dtype])
-    np.testing.assert_allclose(m, wm, atol=1e-5, rtol=1e-5)
-    np.testing.assert_allclose(l, wl, atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(m[live], wm[live], atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(l[live], wl[live], atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("b,h,kv,d,page,maxp", PAGED_SHAPES)
-@pytest.mark.parametrize("which", ["first", "middle", "last"])
-def test_paged_attention_stacked_pool(b, h, kv, d, page, maxp, which):
+@pytest.mark.parametrize("b,h,kv,d,page,maxp,dtype,which", STACKED_CASES)
+def test_paged_attention_stacked_pool(b, h, kv, d, page, maxp, dtype,
+                                      which):
     """The whole stack's pool [L, NB, P, KV*D] with a layer index (a
     scalar-prefetch operand of the kernel, a direct pool[layer, table]
     gather in the jnp lowerings) reads exactly that layer: the kernel
@@ -110,15 +142,14 @@ def test_paged_attention_stacked_pool(b, h, kv, d, page, maxp, which):
     li = {"first": 0, "middle": 1, "last": n_layers - 1}[which]
     k = jax.random.key(5)
     nb = b * maxp + 4
-    q = jax.random.normal(jax.random.fold_in(k, 1), (b, h, d))
+    q = jax.random.normal(jax.random.fold_in(k, 1), (b, h, d), dtype)
     kp = jax.random.normal(jax.random.fold_in(k, 2),
-                           (n_layers, nb, page, kv * d))
+                           (n_layers, nb, page, kv * d), dtype)
     vp = jax.random.normal(jax.random.fold_in(k, 3),
-                           (n_layers, nb, page, kv * d))
+                           (n_layers, nb, page, kv * d), dtype)
     table = jax.random.permutation(
         jax.random.fold_in(k, 4), jnp.arange(nb))[:b * maxp].reshape(b, maxp)
-    ctx = jnp.asarray([(maxp * page * (i + 1)) // (b + 1) + 1
-                       for i in range(b)], jnp.int32)
+    ctx = _paged_ctx(b, page, maxp, kv * d, dtype)
     got, alone = {}, {}
     for impl in ("pallas_interpret", "blocked", "naive"):
         got[impl], _ = jax.jit(lambda q, kp, vp, t, c, li: ops.paged_attention(
@@ -130,28 +161,83 @@ def test_paged_attention_stacked_pool(b, h, kv, d, page, maxp, which):
     want = ref.paged_attention_naive(
         q, kp[li].reshape(nb, page, kv, d), vp[li].reshape(nb, page, kv, d),
         table, ctx)
+    tol = 2e-5 if dtype == jnp.float32 else TOL[dtype]
+    live = np.asarray(ctx) > 0
     for impl in got:
-        np.testing.assert_allclose(got[impl], want, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(np.asarray(got[impl], np.float32)[live],
+                                   np.asarray(want, np.float32)[live],
+                                   atol=tol, rtol=tol)
     # another layer's pages give another answer: the index is not ignored
     other = ops.paged_attention(q, kp[li - 1], vp[li - 1], table, ctx,
                                 impl="naive")
-    assert not np.allclose(got["pallas_interpret"], other, atol=1e-3)
+    assert not np.allclose(np.asarray(got["pallas_interpret"], np.float32),
+                           np.asarray(other, np.float32), atol=1e-3)
 
 
-def test_paged_attention_softcap():
+@pytest.mark.parametrize("kwargs", [
+    dict(softcap=25.0),
+    # windows whose lower pages are dead; at 150 the live pages of the
+    # longest lane span two blocks
+    dict(window=40), dict(window=40, softcap=25.0), dict(window=150),
+], ids=["softcap", "window", "window-softcap", "window-two-blocks"])
+def test_paged_attention_softcap(kwargs):
     k = jax.random.key(4)
-    b, h, kv, d, page, maxp = 2, 4, 2, 32, 8, 4
+    b, h, kv, d, page, maxp = 3, 8, 8, 128, 16, 16    # ppb 8 in f32
     nb = b * maxp
     q = jax.random.normal(jax.random.fold_in(k, 1), (b, h, d))
     kp = jax.random.normal(jax.random.fold_in(k, 2), (nb, page, kv, d))
     vp = jax.random.normal(jax.random.fold_in(k, 3), (nb, page, kv, d))
     table = jnp.arange(nb).reshape(b, maxp)
-    ctx = jnp.array([17, 30])
+    ctx = jnp.array([17, 30, 250])
     out = pa.paged_attention(q, kp.reshape(nb, page, kv * d),
                              vp.reshape(nb, page, kv * d), table, ctx,
-                             softcap=25.0, interpret=True)
-    want = ref.paged_attention_naive(q, kp, vp, table, ctx, softcap=25.0)
+                             interpret=True, **kwargs)
+    want = ref.paged_attention_naive(q, kp, vp, table, ctx, **kwargs)
     np.testing.assert_allclose(out, want, atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("dtype,window", [(jnp.float32, 0),
+                                          (jnp.bfloat16, 40)])
+def test_paged_attention_never_reads_dead_pages(window, dtype):
+    """Dead pages are never copied, and rows past ctx never count: every
+    table entry past a lane's live pages (and below its window) names a
+    block outside the pool, which the interpreter's bounds check
+    refuses to copy; the rows past ctx in each lane's last live page
+    are NaN. The output is finite and equal, bit for bit, to the call
+    on the clean table and pool. Lane 0 is dead: no entry is valid."""
+    k = jax.random.key(13)
+    b, h, kv, d, page, maxp = PAGED_SHAPES[-1]
+    nb = b * maxp
+    q = jax.random.normal(jax.random.fold_in(k, 1), (b, h, d), dtype)
+    kp = jax.random.normal(jax.random.fold_in(k, 2), (nb, page, kv * d),
+                           dtype)
+    vp = jax.random.normal(jax.random.fold_in(k, 3), (nb, page, kv * d),
+                           dtype)
+    table = np.arange(nb, dtype=np.int32).reshape(b, maxp)
+    ctx = np.array(_paged_ctx(b, page, maxp, kv * d, dtype))
+    ctx[1] = 200                       # a page and a half past a window
+    live_hi = -(-ctx // page)
+    live_lo = np.maximum(ctx - window, 0) // page if window else 0 * ctx
+    j = np.arange(maxp)[None, :]
+    dead = (j >= live_hi[:, None]) | (j < live_lo[:, None])
+    poisoned = np.where(dead, nb + 1000 + j, table).astype(np.int32)
+    kpn, vpn = np.array(kp), np.array(vp)
+    for lane in range(b):
+        if ctx[lane] % page:
+            last = table[lane, live_hi[lane] - 1]
+            kpn[last, ctx[lane] % page:] = np.nan
+            vpn[last, ctx[lane] % page:] = np.nan
+    run = lambda t, kk, vv: pa.paged_attention(
+        q, jnp.asarray(kk), jnp.asarray(vv), jnp.asarray(t),
+        jnp.asarray(ctx), window=window, return_stats=True, interpret=True)
+    clean, (cm, cl) = run(table, kp, vp)
+    got, (m, l) = run(poisoned, kpn, vpn)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(clean, np.float32))
+    np.testing.assert_array_equal(m, cm)
+    np.testing.assert_array_equal(l, cl)
+    _assert_dead_lanes(ctx, got, m, l)
 
 
 @pytest.mark.parametrize("kernel", ["flash", "paged"])

@@ -469,6 +469,39 @@ def test_admission_costs_one_host_sync(macro_k):
         assert eng.metrics["host_syncs"] - e0 == n_admit + 1
 
 
+@pytest.mark.parametrize("macro_k", [1, 4])
+def test_paged_attention_page_counters(macro_k):
+    """Each decode dispatch adds its resident lanes' pages at scan end
+    to ``pa_live_pages`` and lanes x its page bucket to
+    ``pa_bucket_pages``: the pages the paged-attention kernel copies
+    against those a grid of one page a step over the bucket copied."""
+    cfg = smoke_config(get_arch("llama3.2-1b"))
+    m = build_model(cfg, RT)
+    params = m.init(jax.random.key(0))
+    eng = ServeEngine(m, params, config=ServeConfig(
+        n_slots=3, max_ctx=128, macro_k=macro_k))
+    buckets = []
+    for name in ("_decode", "_macro", "_macro_simple"):
+        fn = getattr(eng, name)
+        if fn is not None:       # the page bucket is every call's last
+            setattr(eng, name, lambda *a, fn=fn: (buckets.append(a[-1]),
+                                                  fn(*a))[1])
+    eng.submit(list(range(1, 20)), max_new=10 ** 6)
+    eng.submit(list(range(30, 34)), max_new=10 ** 6)
+    want_live = want_bucket = 0
+    done: dict = {}
+    for _ in range(8):
+        n = len(buckets)
+        eng.step(done)
+        held = [len(eng.kvm.seq_pages[r.slot]) for r in eng.active.values()]
+        assert len(buckets) == n + 1
+        want_live += sum(held)
+        want_bucket += len(held) * buckets[-1]
+    assert eng.metrics["pa_live_pages"] == want_live
+    assert eng.metrics["pa_bucket_pages"] == want_bucket
+    assert 0 < want_live < want_bucket
+
+
 def _serve_spans(trace_dir):
     """(name, start_ns, end_ns, stats) of every ``serve.*`` host span in
     the profile written under ``trace_dir``, in start order."""

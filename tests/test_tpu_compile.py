@@ -102,6 +102,26 @@ def test_paged_attention_compiles_at_llama_decode_widths(one_chip):
         S((B,), jnp.int32))
 
 
+@pytest.mark.parametrize("b,kv,n_layers", [(32, 8, 8), (16, 2, 20)],
+                         ids=["mistral-7b", "glm4-9b"])
+def test_paged_attention_compiles_on_the_stacked_pool(one_chip, b, kv,
+                                                      n_layers):
+    """The kernel on the stack's bf16 pool [L, NB, P, KV*D] at the chip
+    benchmark's decode widths (H=32, D=128, page 16, a 256-page bucket)
+    with a traced layer index: Mosaic takes the per-page copies into
+    the block buffers, and the stack stays in place — no op of the
+    compiled module copies or slices it, or one layer of it."""
+    from repro.kernels import paged_attention as pa
+    S = _sds(one_chip)
+    h, d, maxp, bf16 = 32, 128, 256, jnp.bfloat16
+    pool = (n_layers, b * maxp + 1, PAGE, kv * d)
+    compiled = _compile(lambda q, k, v, t, c, li: pa.paged_attention(
+        q, k, v, t, c, layer=li, return_stats=True),
+        S((b, h, d), bf16), S(pool, bf16), S(pool, bf16),
+        S((b, maxp), jnp.int32), S((b,), jnp.int32), S((), jnp.int32))
+    assert _pool_moves(compiled.as_text(), [pool, pool[1:]]) == []
+
+
 def test_fmmu_translate_compiles_at_serving_geometry(one_chip):
     from repro.kernels import fmmu_translate as ft
     g, args = _translate_args(_sds(one_chip))
@@ -204,12 +224,10 @@ _POOL_MOVES = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice")
 _INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][a-z0-9-]*)\(")
 
 
-def _pool_moves(text, pool_shape):
+def _pool_moves(text, dims):
     """Instructions of the compiled ``text`` that copy or slice a result
-    shaped like the stacked pool, the stack seen as [L, ...] or one
-    layer's pool: (name, opcode, type) each."""
-    n_per, n_attn, *layer = pool_shape
-    dims = [pool_shape, (n_per * n_attn, *layer), layer]
+    shaped like one of ``dims`` (the stacked pool, as the callers see
+    it, and one layer's pool): (name, opcode, type) each."""
     shaped = re.compile(r"\[(%s)\]" % "|".join(
         re.escape(",".join(map(str, d))) for d in dims))
     found = []
@@ -273,7 +291,9 @@ def _macro_step_checks(topo, one_chip, monkeypatch, variant):
     assert used < HBM_BYTES, used
     pool = eng.caches["pool_k"]
     assert pool.shape == (16, 1, N_SLOTS * MAX_PAGES + 1, PAGE, KV * D)
-    assert _pool_moves(text, pool.shape) == []
+    n_per, n_attn, *layer = pool.shape
+    assert _pool_moves(text, [pool.shape, (n_per * n_attn, *layer),
+                              layer]) == []
     layer_kv_bytes = 2 * math.prod(pool.shape[2:]) * pool.dtype.itemsize
     assert mem.temp_size_in_bytes < layer_kv_bytes, mem.temp_size_in_bytes
 
